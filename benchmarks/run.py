@@ -134,6 +134,9 @@ def main() -> None:
                          "canonical BENCH_results.json at the repo root)")
     args, _ = ap.parse_known_args()
     quick = not args.full
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     results = {}
     rows = []
 

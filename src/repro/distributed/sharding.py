@@ -15,7 +15,6 @@ single-pod mesh and the 2x16x16 multi-pod mesh).
 """
 from __future__ import annotations
 
-import inspect
 from dataclasses import dataclass
 from functools import cached_property
 from typing import Any, Sequence
@@ -23,28 +22,6 @@ from typing import Any, Sequence
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# `shard_map` moved from jax.experimental to the jax top level, and the
-# replication-check kwarg was renamed check_rep -> check_vma along the way.
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_CHECK_KWARG = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable `shard_map` with replication checking off by default
-    (model code relies on unchecked psums over replicated axes)."""
-    return _shard_map_impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_SM_CHECK_KWARG: check},
-    )
 
 
 def logical_to_mesh(mesh: Mesh) -> dict[str, Any]:

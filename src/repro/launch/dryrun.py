@@ -28,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config, get_shape
 from repro.distributed.sharding import ShardingCtx, sanitized_shardings, tree_shardings
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import model as M
 from repro.models import transformer
 from repro.types import SHAPES, TrainConfig, V5E
@@ -325,9 +325,9 @@ def main():
     if args.mesh == "tiny":
         n = len(jax.devices())
         if n >= 8:
-            meshes.append(("tiny", jax.make_mesh((2, 2, 2), ("pod", "data", "model"))))
+            meshes.append(("tiny", make_mesh((2, 2, 2), ("pod", "data", "model"))))
         else:
-            meshes.append(("tiny", jax.make_mesh((1, max(n, 1)), ("data", "model"))))
+            meshes.append(("tiny", make_mesh((1, max(n, 1)), ("data", "model"))))
 
     outdir = Path(args.out)
     outdir.mkdir(parents=True, exist_ok=True)

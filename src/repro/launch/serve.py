@@ -1,8 +1,8 @@
 """UQ serving driver — the paper's deployment shape.
 
-Starts an UM-Bridge HTTP server exposing the built-in models (L2-Sea
-analogue, composite ROM, tsunami, or an LM wrapped as a UQ model), each
-backed by the SPMD ModelPool for parallel evaluation:
+Starts an UM-Bridge HTTP server exposing one built-in model (L2-Sea
+analogue, composite ROM, tsunami, or an LM wrapped as a UQ model); compiled
+programs persist in the compile cache (`repro.launch.compile_cache`):
 
     PYTHONPATH=src python -m repro.launch.serve --model l2sea --port 4242
 
@@ -17,9 +17,8 @@ import argparse
 
 import jax
 
-from repro.core.pool import ModelPool
 from repro.core.server import serve_models
-from repro.distributed.sharding import ShardingCtx, make_test_mesh
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_model(name: str, arch: str, reduced: bool):
@@ -50,6 +49,7 @@ def main():
     ap.add_argument("--port", type=int, default=4242)
     args = ap.parse_args()
 
+    enable_compile_cache()
     model = build_model(args.model, args.arch, args.reduced)
     print(f"serving '{model.name}' on http://0.0.0.0:{args.port} "
           f"(devices: {len(jax.devices())})")

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map_compat
 from repro.models.layers import decl_mlp, mlp
 from repro.models.params import ParamDecl
 from repro.types import ModelConfig
@@ -168,19 +167,23 @@ def moe_block(
                 xb, ib, wb, g_, u_, d_,
                 cfg=cfg, tp_axis="model", fsdp_axis="data", capacity=capacity,
             )
-        yf = shard_map_compat(
+        # check_vma off: the expert body psums over replicated axes
+        yf = jax.shard_map(
             body_nomodel,
-            mesh,
+            mesh=mesh,
             in_specs=(token_spec, token_spec, token_spec, expert_spec, expert_spec, expert_spec_d),
             out_specs=token_spec,
+            check_vma=False,
         )(xf, idxf, wf, params["w_gate"], params["w_up"], params["w_down"])
         yf = yf / n_tp  # psum over replicated model shards overcounts
     else:
-        yf = shard_map_compat(
+        # check_vma off: the expert body psums over replicated axes
+        yf = jax.shard_map(
             body,
-            mesh,
+            mesh=mesh,
             in_specs=(token_spec, token_spec, token_spec, expert_spec, expert_spec, expert_spec_d),
             out_specs=token_spec,
+            check_vma=False,
         )(xf, idxf, wf, params["w_gate"], params["w_up"], params["w_down"])
 
     y = yf.reshape(B, S, d)

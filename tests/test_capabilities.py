@@ -180,11 +180,9 @@ def test_fd_matches_autodiff_on_composite():
     """Satellite regression: the relative-step FD fallback against the AD
     path on CompositeModel's differentiable (smooth-defect) full solve,
     under x64 so float noise does not swamp the small energy sensitivities."""
-    from jax.experimental import enable_x64
-
     from repro.apps.composite import CompositeModel
 
-    with enable_x64():
+    with jax.enable_x64(True):
         m = CompositeModel()
         cfg = {"mode": "full", "defect_softness": 1.0}
         thetas = np.array([[77.5, 210.0, 10.0], [70.0, 205.0, 8.0]])

@@ -302,9 +302,9 @@ def test_speculative_respawn_shares_retry_budget():
 
 
 def test_model_pool_honors_x64():
-    from jax.experimental import enable_x64
+    import jax
 
-    with enable_x64():
+    with jax.enable_x64(True):
         m = JAXModel(lambda th: th * 1.0, 1, 1)
         pool = ModelPool(m)
         out = pool.evaluate(np.array([[1.0 + 1e-12]]))
