@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.races import named_lock
+from repro.core import tracing
 from repro.core.interface import (
     Capabilities,
     Model,
@@ -378,6 +379,10 @@ class TsunamiModel(Model):
         # the fabric/server dispatch waves from several threads at once
         self._lock = named_lock("tsunami.stats")
         self.stats = {0: 0, 1: 0}
+        #: per level: lanes dispatched (padding included), padded lanes and
+        #: chunk programs run
+        self.chunk_stats = {lv: {"lanes": 0, "padded": 0, "programs": 0}
+                            for lv in self.N_CELLS}
         self._vgrad_cache: "OrderedDict" = OrderedDict()
 
     def get_input_sizes(self, config=None):
@@ -402,42 +407,56 @@ class TsunamiModel(Model):
         obs = observables(theta, self.N_CELLS[level], smoothed=(level == 0))
         return [list(map(float, obs))]
 
-    def evaluate_batch(self, thetas, config=None) -> np.ndarray:
-        """[N, 2] -> [N, 4] through the lockstep batch solver.
+    def padded_lanes(self) -> int:
+        """Lanes added by chunk padding, over every wave so far."""
+        with self._lock:
+            return sum(c["padded"] for c in self.chunk_stats.values())
 
-        The batch is split into power-of-2-wide chunks (<= 64 lanes, so the
-        jit cache holds at most a handful of shapes per level) solved
-        CONCURRENTLY on the host executor — see `_solve_batch` for why
-        chunked thread-parallelism beats one monolithic dispatch on CPU."""
+    def _map_chunks(self, level: int, N: int, chunk_max: int, solve) -> list:
+        """[solve(lo, hi, bucket), ...] over the chunks of a wave of N lanes:
+        lanes [lo, hi) padded to `bucket`, a power of two, so the jit cache
+        holds a handful of shapes per level. Several chunks run CONCURRENTLY
+        on the host executor (see `_solve_batch` for why that beats one
+        monolithic dispatch on CPU), each in a `model.chunk` span under the
+        caller's span."""
+        workers = max(os.cpu_count() or 1, 1)
+        chunk = int(np.clip(next_pow2(-(-N // workers)), _CHUNK_MIN, chunk_max))
+        plan = [(lo, min(lo + chunk, N), next_pow2(max(min(chunk, N - lo), _CHUNK_MIN)))
+                for lo in range(0, N, chunk)]
+        lanes = sum(bucket for _, _, bucket in plan)
+        with self._lock:
+            self.stats[level] += N
+            counts = self.chunk_stats[level]
+            counts["lanes"] += lanes
+            counts["padded"] += lanes - N
+            counts["programs"] += len(plan)
+        parent = tracing.current()
+
+        def run(part):
+            lo, hi, bucket = part
+            with tracing.span("model.chunk", parent=parent, level=level,
+                              lanes=bucket, padded=bucket - (hi - lo)):
+                return solve(lo, hi, bucket)
+
+        if len(plan) == 1:
+            return [run(plan[0])]
+        return list(_chunk_executor().map(run, plan))
+
+    def evaluate_batch(self, thetas, config=None) -> np.ndarray:
+        """[N, 2] -> [N, 4] through the lockstep batch solver, in chunks of
+        at most 64 lanes (`_map_chunks`)."""
         level = int((config or {}).get("level", 0))
         n_cells, smoothed = self.N_CELLS[level], (level == 0)
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
-        N = len(thetas)
-        with self._lock:
-            self.stats[level] += N
-        workers = max(os.cpu_count() or 1, 1)
-        chunk = int(np.clip(next_pow2(-(-N // workers)), _CHUNK_MIN, _CHUNK_MAX))
 
-        def solve_chunk(lo: int) -> np.ndarray:
-            part = thetas[lo : lo + chunk]
-            padded, _ = pad_to_bucket(part, next_pow2(max(len(part), _CHUNK_MIN)))
+        def solve(lo, hi, bucket):
+            padded, _ = pad_to_bucket(thetas[lo:hi], bucket)
             out = _solve_batch(jnp.asarray(padded), n_cells, smoothed, _swe_impl())
-            return np.asarray(out, float)[: len(part)]
+            return np.asarray(tracing.wait(out), float)[: hi - lo]
 
-        starts = range(0, N, chunk)
-        if len(starts) == 1:
-            return solve_chunk(0)
-        rows = list(_chunk_executor().map(solve_chunk, starts))
-        return np.concatenate(rows, axis=0)
+        return np.concatenate(self._map_chunks(level, len(thetas), _CHUNK_MAX, solve), axis=0)
 
     # -- batched derivative surface -----------------------------------------
-    def _grad_chunks(self, N: int) -> tuple[int, range]:
-        workers = max(os.cpu_count() or 1, 1)
-        chunk = int(np.clip(
-            next_pow2(-(-N // workers)), _CHUNK_MIN, self.GRAD_CHUNK_MAX
-        ))
-        return chunk, range(0, N, chunk)
-
     def gradient(self, out_wrt, in_wrt, parameters, sens, config=None):
         theta = np.asarray(parameters[in_wrt], float)
         sens4 = np.zeros(4)
@@ -452,23 +471,15 @@ class TsunamiModel(Model):
         n_cells, smoothed = self.N_CELLS[level], (level == 0)
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
         senss = np.atleast_2d(np.asarray(senss, np.float32))
-        N = len(thetas)
-        with self._lock:
-            self.stats[level] += N
-        chunk, starts = self._grad_chunks(N)
 
-        def grad_chunk(lo: int) -> np.ndarray:
-            part = thetas[lo: lo + chunk]
-            spart = senss[lo: lo + chunk]
-            bucket = next_pow2(max(len(part), _CHUNK_MIN))
-            pt, _ = pad_to_bucket(part, bucket)
-            ps, _ = pad_to_bucket(spart, bucket)
+        def solve(lo, hi, bucket):
+            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
+            ps, _ = pad_to_bucket(senss[lo:hi], bucket)
             _, g = _vjp_batch(jnp.asarray(pt), jnp.asarray(ps), n_cells, smoothed)
-            return np.asarray(g, float)[: len(part)]
+            return np.asarray(tracing.wait(g), float)[: hi - lo]
 
-        if len(starts) == 1:
-            return grad_chunk(0)
-        return np.concatenate(list(_chunk_executor().map(grad_chunk, starts)), axis=0)
+        return np.concatenate(
+            self._map_chunks(level, len(thetas), self.GRAD_CHUNK_MAX, solve), axis=0)
 
     def apply_jacobian(self, out_wrt, in_wrt, parameters, vec, config=None):
         theta = np.asarray(parameters[in_wrt], float)
@@ -482,23 +493,15 @@ class TsunamiModel(Model):
         n_cells, smoothed = self.N_CELLS[level], (level == 0)
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
         vecs = np.atleast_2d(np.asarray(vecs, np.float32))
-        N = len(thetas)
-        with self._lock:
-            self.stats[level] += N
-        chunk, starts = self._grad_chunks(N)
 
-        def jvp_chunk(lo: int) -> np.ndarray:
-            part = thetas[lo: lo + chunk]
-            vpart = vecs[lo: lo + chunk]
-            bucket = next_pow2(max(len(part), _CHUNK_MIN))
-            pt, _ = pad_to_bucket(part, bucket)
-            pv, _ = pad_to_bucket(vpart, bucket)
+        def solve(lo, hi, bucket):
+            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
+            pv, _ = pad_to_bucket(vecs[lo:hi], bucket)
             out = _jvp_batch(jnp.asarray(pt), jnp.asarray(pv), n_cells, smoothed)
-            return np.asarray(out, float)[: len(part)]
+            return np.asarray(tracing.wait(out), float)[: hi - lo]
 
-        if len(starts) == 1:
-            return jvp_chunk(0)
-        return np.concatenate(list(_chunk_executor().map(jvp_chunk, starts)), axis=0)
+        return np.concatenate(
+            self._map_chunks(level, len(thetas), self.GRAD_CHUNK_MAX, solve), axis=0)
 
     def apply_hessian(self, out_wrt, in_wrt1, in_wrt2, parameters, sens, vec, config=None):
         theta = np.asarray(parameters[in_wrt1], float)
@@ -518,27 +521,18 @@ class TsunamiModel(Model):
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
         senss = np.atleast_2d(np.asarray(senss, np.float32))
         vecs = np.atleast_2d(np.asarray(vecs, np.float32))
-        N = len(thetas)
-        with self._lock:
-            self.stats[level] += N
-        chunk, starts = self._grad_chunks(N)
 
-        def hvp_chunk(lo: int) -> np.ndarray:
-            part = thetas[lo: lo + chunk]
-            spart = senss[lo: lo + chunk]
-            vpart = vecs[lo: lo + chunk]
-            bucket = next_pow2(max(len(part), _CHUNK_MIN))
-            pt, _ = pad_to_bucket(part, bucket)
-            ps, _ = pad_to_bucket(spart, bucket)
-            pv, _ = pad_to_bucket(vpart, bucket)
+        def solve(lo, hi, bucket):
+            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
+            ps, _ = pad_to_bucket(senss[lo:hi], bucket)
+            pv, _ = pad_to_bucket(vecs[lo:hi], bucket)
             out = _hvp_batch(
                 jnp.asarray(pt), jnp.asarray(ps), jnp.asarray(pv), n_cells, smoothed
             )
-            return np.asarray(out, float)[: len(part)]
+            return np.asarray(tracing.wait(out), float)[: hi - lo]
 
-        if len(starts) == 1:
-            return hvp_chunk(0)
-        return np.concatenate(list(_chunk_executor().map(hvp_chunk, starts)), axis=0)
+        return np.concatenate(
+            self._map_chunks(level, len(thetas), self.GRAD_CHUNK_MAX, solve), axis=0)
 
     def value_and_gradient_batch(self, thetas, sens_fn, config=None):
         """Fused (ys, grads) in ONE jitted dispatch per chunk when `sens_fn`
@@ -566,23 +560,15 @@ class TsunamiModel(Model):
                     self._vgrad_cache.popitem(last=False)
             self._vgrad_cache.move_to_end(key)
             fused_fn = self._vgrad_cache[key]
-        chunk, starts = self._grad_chunks(N)
 
-        def fused_chunk(lo: int):
-            part = thetas[lo: lo + chunk]
-            pt, _ = pad_to_bucket(part, next_pow2(max(len(part), _CHUNK_MIN)))
-            y, g = fused_fn(jnp.asarray(pt))
-            return np.asarray(y, float)[: len(part)], np.asarray(g, float)[: len(part)]
+        def solve(lo, hi, bucket):
+            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
+            y, g = tracing.wait(fused_fn(jnp.asarray(pt)))
+            return np.asarray(y, float)[: hi - lo], np.asarray(g, float)[: hi - lo]
 
-        if len(starts) == 1:
-            ys, gs = fused_chunk(0)
-        else:
-            parts = list(_chunk_executor().map(fused_chunk, starts))
-            ys = np.concatenate([p[0] for p in parts], axis=0)
-            gs = np.concatenate([p[1] for p in parts], axis=0)
-        with self._lock:
-            self.stats[level] += N
-        return ys, gs
+        parts = self._map_chunks(level, N, self.GRAD_CHUNK_MAX, solve)
+        return (np.concatenate([p[0] for p in parts], axis=0),
+                np.concatenate([p[1] for p in parts], axis=0))
 
 
 def make_logposts(model: TsunamiModel, data: np.ndarray, noise_sd, prior_bounds):

@@ -41,7 +41,7 @@ with
     observers exactly once (cache hits and coalesced waiters are never
     replayed), so online surrogates (`uq.surrogate.SurrogateStore`) train
     from traffic the sampler already paid for, with zero extra evaluations;
-  * per-backend telemetry — waves, points, padding waste, busy fraction,
+  * per-backend telemetry — waves, points, padding waste, busy seconds,
     cache hits, and a per-capability wave/point split — so benchmarks can
     report the paper's efficiency numbers and gradient-sampler economics.
 
@@ -63,6 +63,7 @@ from typing import Callable, Sequence
 import numpy as np
 
 from repro.analysis.races import named_condition, named_lock
+from repro.core import tracing
 from repro.core.interface import (
     Capabilities,
     JAXModel,
@@ -323,6 +324,10 @@ class ModelBackend(FabricBackend):
 
     def evaluate(self, thetas, config):
         thetas = np.atleast_2d(np.asarray(thetas, float))
+        with tracing.span("backend.dispatch", op="evaluate", lanes=len(thetas)):
+            return self._evaluate(thetas, config)
+
+    def _evaluate(self, thetas, config):
         N = len(thetas)
         if self.native:
             pad = 0
@@ -370,6 +375,10 @@ class ModelBackend(FabricBackend):
             )
         with self._lock:
             self._op_stats[op] = self._op_stats.get(op, 0) + 1
+        with tracing.span("backend.dispatch", op=op, lanes=len(thetas)):
+            return self._dispatch_derivative(op, thetas, extra, config)
+
+    def _dispatch_derivative(self, op, thetas, extra, config):
         if op == "gradient":
             return np.atleast_2d(np.asarray(
                 self.model.gradient_batch(thetas, extra, config), float
@@ -399,6 +408,11 @@ class ModelBackend(FabricBackend):
         rt = getattr(self.model, "round_trips", None)
         if rt is not None:
             s["round_trips"] = rt
+        # lanes the model itself padded (chunking inside `evaluate_batch`);
+        # `padded` above counts only this dispatcher's bucketing
+        padded_lanes = getattr(self.model, "padded_lanes", None)
+        if padded_lanes is not None:
+            s["model_padded"] = int(padded_lanes())
         return s
 
 
@@ -1622,6 +1636,10 @@ class EvaluationFabric:
         """[N, n] -> [N, m] in ONE backend dispatch (bypasses the collector —
         an explicit batch is already a wave), deduping repeated rows and
         cache hits first. `tenant`/`namespace` as in `submit`."""
+        with tracing.span("fabric.evaluate", op="evaluate") as sp:
+            return self._evaluate_batch(thetas, config, tenant, namespace, sp)
+
+    def _evaluate_batch(self, thetas, config, tenant, namespace, sp):
         thetas = np.atleast_2d(np.asarray(thetas, float))
         N = len(thetas)
         keys = [self._key(t, config, ns=namespace) for t in thetas]
@@ -1665,6 +1683,7 @@ class EvaluationFabric:
                 miss_thetas.append(thetas[i])
                 self._inflight[key] = Future()
                 self._inflight_owner[key] = tenant
+        sp.set(points=len(miss_order), hits=N - len(miss_order))
         outs = None
         if miss_order:
             t0 = time.monotonic()
@@ -1753,6 +1772,11 @@ class EvaluationFabric:
     def _derivative_wave(self, op: str, thetas, extras, config, *,
                          tenant: str | None = None,
                          namespace: str | None = None) -> np.ndarray:
+        with tracing.span(f"fabric.{op}", op=op) as sp:
+            return self._derivative_wave_rows(op, thetas, extras, config, tenant,
+                                            namespace, sp)
+
+    def _derivative_wave_rows(self, op, thetas, extras, config, tenant, namespace, sp):
         thetas = np.atleast_2d(np.asarray(thetas, float))
         if isinstance(extras, tuple):
             # two-operand wave (apply_hessian): both arrays shard with the
@@ -1810,6 +1834,7 @@ class EvaluationFabric:
                 miss_rows[key] = len(miss_order)
                 miss_order.append(key)
                 miss_idx.append(i)
+        sp.set(points=len(miss_order), hits=N - len(miss_order))
         outs = None
         if miss_order:
             miss_extras = (
@@ -1853,29 +1878,31 @@ class EvaluationFabric:
         cache-served through the two-wave path when it matters."""
         thetas = np.atleast_2d(np.asarray(thetas, float))
         if getattr(self.backend, "fused_value_grad", False):
-            t0 = time.monotonic()
-            ys, grads = self.backend.dispatch(
-                "value_and_gradient", thetas, sens_fn, config
-            )
-            wall = time.monotonic() - t0
-            ys = np.atleast_2d(np.asarray(ys, float))
-            grads = np.atleast_2d(np.asarray(grads, float))
-            with self._lock:
-                if self._stop:
-                    raise RuntimeError("fabric is shut down")
-                self.stats["waves"] += 1
-                self.stats["points"] += len(thetas)
-                self.stats["fill_sum"] += 1.0
-                self._label_bump(config, points=len(thetas), waves=1)
-                self._capability_bump(
-                    "value_and_gradient", points=len(thetas), waves=1
+            with tracing.span("fabric.value_and_gradient", op="value_and_gradient",
+                              points=len(thetas), hits=0):
+                t0 = time.monotonic()
+                ys, grads = self.backend.dispatch(
+                    "value_and_gradient", thetas, sens_fn, config
                 )
-                self._tenant_bump(tenant, points=len(thetas), waves=1,
-                                  backend_s=wall)
-            # fused waves carry fresh forward values too — observers that
-            # train on (theta, y) pairs filter on the op themselves
-            self._notify_observers("value_and_gradient", thetas, ys, config)
-            return ys, grads
+                wall = time.monotonic() - t0
+                ys = np.atleast_2d(np.asarray(ys, float))
+                grads = np.atleast_2d(np.asarray(grads, float))
+                with self._lock:
+                    if self._stop:
+                        raise RuntimeError("fabric is shut down")
+                    self.stats["waves"] += 1
+                    self.stats["points"] += len(thetas)
+                    self.stats["fill_sum"] += 1.0
+                    self._label_bump(config, points=len(thetas), waves=1)
+                    self._capability_bump(
+                        "value_and_gradient", points=len(thetas), waves=1
+                    )
+                    self._tenant_bump(tenant, points=len(thetas), waves=1,
+                                      backend_s=wall)
+                # fused waves carry fresh forward values too — observers that
+                # train on (theta, y) pairs filter on the op themselves
+                self._notify_observers("value_and_gradient", thetas, ys, config)
+                return ys, grads
         if not _backend_op_ok(self.backend, "gradient"):
             raise UnsupportedCapability(
                 "fabric backend advertises no 'gradient' capability — "
@@ -1914,50 +1941,51 @@ class EvaluationFabric:
             for items in groups.values():
                 stack = np.stack([it[0] for it in items])
                 t_grp = time.monotonic()
-                try:
-                    outs = np.atleast_2d(
-                        np.asarray(self.backend.evaluate(stack, items[0][1]))
-                    )
-                    if outs.shape[0] != len(items):
-                        outs = outs.T
-                    grp_wall = time.monotonic() - t_grp
-                    # tap snapshot BEFORE futures resolve: the original
-                    # submitter gets the raw rows and may mutate its
-                    # result in place the instant set_result runs
-                    tap_outs = np.array(outs[: len(items)])
-                    # per-tenant share of this group: a mixed collector wave
-                    # charges each tenant its point count and a proportional
-                    # slice of the measured dispatch wall
-                    tenant_points: dict[str, int] = {}
-                    for it in items:
-                        if it[4] is not None:
-                            tenant_points[it[4]] = tenant_points.get(it[4], 0) + 1
-                    with self._lock:
-                        self._label_bump(items[0][1], points=len(items), waves=1)
-                        self._capability_bump(
-                            "evaluate", points=len(items), waves=1
+                with tracing.span("fabric.evaluate", op="evaluate", points=len(items), hits=0):
+                    try:
+                        outs = np.atleast_2d(
+                            np.asarray(self.backend.evaluate(stack, items[0][1]))
                         )
-                        for tname, n_t in tenant_points.items():
-                            self._tenant_bump(
-                                tname, points=n_t, waves=1,
-                                backend_s=grp_wall * n_t / len(items),
+                        if outs.shape[0] != len(items):
+                            outs = outs.T
+                        grp_wall = time.monotonic() - t_grp
+                        # tap snapshot BEFORE futures resolve: the original
+                        # submitter gets the raw rows and may mutate its
+                        # result in place the instant set_result runs
+                        tap_outs = np.array(outs[: len(items)])
+                        # per-tenant share of this group: a mixed collector wave
+                        # charges each tenant its point count and a proportional
+                        # slice of the measured dispatch wall
+                        tenant_points: dict[str, int] = {}
+                        for it in items:
+                            if it[4] is not None:
+                                tenant_points[it[4]] = tenant_points.get(it[4], 0) + 1
+                        with self._lock:
+                            self._label_bump(items[0][1], points=len(items), waves=1)
+                            self._capability_bump(
+                                "evaluate", points=len(items), waves=1
                             )
-                        for (_, _, fut, key, tname), out in zip(items, outs):
-                            self._cache_put(key, out, tname)
-                            self._inflight.pop(key, None)
-                            self._inflight_owner.pop(key, None)
-                            if not fut.done():
-                                fut.set_result(out)
-                    self._notify_observers(
-                        "evaluate", stack, tap_outs, items[0][1]
-                    )
-                except Exception as e:  # noqa: BLE001
-                    with self._lock:
-                        for _, _, fut, key, _tname in items:
-                            self._inflight.pop(key, None)
-                            self._inflight_owner.pop(key, None)
-                            if not fut.done():
-                                fut.set_exception(e)
+                            for tname, n_t in tenant_points.items():
+                                self._tenant_bump(
+                                    tname, points=n_t, waves=1,
+                                    backend_s=grp_wall * n_t / len(items),
+                                )
+                            for (_, _, fut, key, tname), out in zip(items, outs):
+                                self._cache_put(key, out, tname)
+                                self._inflight.pop(key, None)
+                                self._inflight_owner.pop(key, None)
+                                if not fut.done():
+                                    fut.set_result(out)
+                        self._notify_observers(
+                            "evaluate", stack, tap_outs, items[0][1]
+                        )
+                    except Exception as e:  # noqa: BLE001
+                        with self._lock:
+                            for _, _, fut, key, _tname in items:
+                                self._inflight.pop(key, None)
+                                self._inflight_owner.pop(key, None)
+                                if not fut.done():
+                                    fut.set_exception(e)
             with self._lock:
                 self.stats["waves"] += 1
                 self.stats["points"] += len(batch)
@@ -2006,11 +2034,9 @@ class EvaluationFabric:
         s["capabilities"] = sorted(self.capabilities().names())
         s["backend"] = self.backend.stats()
         back = s["backend"]
-        if "padded" in back and s["points"]:
-            s["padding_waste"] = back["padded"] / (back["padded"] + s["points"])
-        if "busy_s" in back and back.get("evaluations"):
-            n_inst = max(1, self.backend.n_instances)
-            s["busy_fraction_hint"] = back["busy_s"] / n_inst
+        padded = back.get("padded", 0) + back.get("model_padded", 0)
+        if ("padded" in back or "model_padded" in back) and s["points"]:
+            s["padding_waste"] = padded / (padded + s["points"])
         if back.get("kind") == "router":
             # fold the router's headline numbers into the flat stats so
             # benchmarks read them without digging into the backend tree

@@ -36,6 +36,7 @@ the only path for non-JAX backends (HTTP models, subprocess fleets); the
 """
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from typing import Callable
 
@@ -43,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.interface import next_pow2, pad_to_bucket
 from repro.uq.mcmc import EnsembleResult
 
@@ -306,11 +308,13 @@ def _run_fused(
 
     every_blocks = max(1, checkpoint_every // S) if checkpoint_every else 0
     for b in range(start_block, n_blocks):
-        carry, (xs_blk, lps_blk) = dispatch(carry)
-        lo = b * S
-        # host pull — ONE round trip per block (per step when per_step=True)
-        samples[:, lo:lo + S] = np.moveaxis(np.asarray(xs_blk), 0, 1)
-        lps_out[:, lo:lo + S] = np.asarray(lps_blk).T
+        with tracing.span("fused.block", steps=S):
+            carry, (xs_blk, lps_blk) = dispatch(carry)
+            lo = b * S
+            # host pull — ONE round trip per block (per step when per_step=True)
+            tracing.wait((xs_blk, lps_blk))
+            samples[:, lo:lo + S] = np.moveaxis(np.asarray(xs_blk), 0, 1)
+            lps_out[:, lo:lo + S] = np.asarray(lps_blk).T
         if telemetry is not None:
             telemetry.note_steps(S, waves=1)
             # service-tier campaigns meter device-resident work through this
@@ -370,6 +374,20 @@ def _init_carry(x0s, key, ctx):
 # ---------------------------------------------------------------------------
 
 
+def _sampler_span(run: Callable) -> Callable:
+    """A `sampler.fused` span around each call of a fused runner, from entry
+    to return, with the chains and the blocks of the result."""
+    @functools.wraps(run)
+    def traced(*args, **kwargs):
+        with tracing.span("sampler.fused") as sp:
+            res = run(*args, **kwargs)
+            sp.set(chains=len(res.samples), blocks=res.n_waves - 1)
+        return res
+
+    return traced
+
+
+@_sampler_span
 def fused_ensemble_rwm(
     logpost_fn: Callable,
     x0s: np.ndarray,
@@ -404,6 +422,7 @@ def fused_ensemble_rwm(
     )
 
 
+@_sampler_span
 def fused_ensemble_pcn(
     loglik_fn: Callable,
     x0s: np.ndarray,
@@ -441,6 +460,7 @@ def fused_ensemble_pcn(
     )
 
 
+@_sampler_span
 def fused_ensemble_mala(
     logpost_fn: Callable,
     x0s: np.ndarray,

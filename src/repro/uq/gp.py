@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.races import named_rlock
+from repro.core import tracing
 
 #: predictive-variance floor relative to the kernel amplitude — the Schur
 #: complement amp - v^T v is computed by subtraction, so near-degenerate
@@ -152,19 +153,21 @@ class GP:
         training sets, and anything downstream that takes log/sqrt/1-over
         of the variance must stay finite."""
         Xq = np.atleast_2d(np.asarray(Xq, np.float32))
-        d = self.X.shape[1]
-        ls = np.exp(self.log_params[:d])
-        amp = np.exp(self.log_params[d])
-        mean_c = self.log_params[d + 2]
-        Ks = np.asarray(_matern52(jnp.asarray(Xq), jnp.asarray(self.X), jnp.asarray(ls), amp))
-        mu = mean_c + Ks @ self._alpha
-        mu = self._ymu + self._ysd * mu
-        if not return_var:
-            return mu
-        v = np.linalg.solve(self._chol, Ks.T)
-        var = amp - np.sum(v * v, axis=0)
-        var = np.maximum(var, _VAR_REL_FLOOR * float(amp) + 1e-300)
-        return mu, var * self._ysd**2
+        with tracing.span("gp.predict", points=len(Xq)):
+            d = self.X.shape[1]
+            ls = np.exp(self.log_params[:d])
+            amp = np.exp(self.log_params[d])
+            mean_c = self.log_params[d + 2]
+            Ks = np.asarray(tracing.wait(
+                _matern52(jnp.asarray(Xq), jnp.asarray(self.X), jnp.asarray(ls), amp)))
+            mu = mean_c + Ks @ self._alpha
+            mu = self._ymu + self._ysd * mu
+            if not return_var:
+                return mu
+            v = np.linalg.solve(self._chol, Ks.T)
+            var = amp - np.sum(v * v, axis=0)
+            var = np.maximum(var, _VAR_REL_FLOOR * float(amp) + 1e-300)
+            return mu, var * self._ysd**2
 
 
 class OnlineGP:

@@ -42,6 +42,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro.core import tracing
 from repro.uq.mcmc import (
     ChainResult,
     PooledCovarianceAdapter,
@@ -654,7 +655,8 @@ def ensemble_mlda(
     n_done = n_samples
     for i in range(start, n_samples):
         try:
-            xs, lps, _ = sampler.step(top, xs, lps)
+            with tracing.span("sampler.mlda_step", step=i):
+                xs, lps, _ = sampler.step(top, xs, lps)
         except BudgetExhausted:
             # campaign budget ran out: the completed finest-level steps are
             # a valid chain prefix; land a final checkpoint at this
