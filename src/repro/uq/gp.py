@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.analysis.races import named_rlock
 from repro.core import tracing
+from repro.core.interface import next_pow2, pad_to_bucket
 
 #: predictive-variance floor relative to the kernel amplitude — the Schur
 #: complement amp - v^T v is computed by subtraction, so near-degenerate
@@ -40,6 +41,21 @@ def _matern52(X1, X2, lengthscales, amp):
     r = jnp.sqrt(r2 + 1e-12)
     s5r = jnp.sqrt(5.0) * r
     return amp * (1.0 + s5r + 5.0 * r2 / 3.0) * jnp.exp(-s5r)
+
+
+#: the cross-kernel as ONE compiled device program, fed padded blocks
+#: (`_padded`) so a shape compiles once per power-of-two bucket
+_kernel = jax.jit(_matern52)
+
+#: smallest query block: single-point queries share one program
+_Q_MIN = 8
+
+
+def _padded(X: np.ndarray, least: int = 1) -> np.ndarray:
+    """[N, d] float32 rows padded to the next power of two (>= `least`).
+    Padded rows only meet padded kernel rows or columns, which are sliced
+    off: every returned entry is exact."""
+    return pad_to_bucket(X, max(least, next_pow2(len(X))))[0]
 
 
 def _nlml(log_params, X, y):
@@ -133,19 +149,28 @@ class GP:
         d = X.shape[1]
         p = np.asarray(log_params, float)
         ls, amp, noise = np.exp(p[:d]), float(np.exp(p[d])), float(np.exp(p[d + 1]))
-        K = np.asarray(
-            _matern52(jnp.asarray(X), jnp.asarray(X), jnp.asarray(ls, jnp.float32), amp),
-            np.float64,
-        ) + (noise + 1e-5 * amp + 1e-8) * np.eye(len(X))
+        # training inputs and hyperparameters live on the device from here:
+        # a prediction uploads only its query block
+        Xd = jax.device_put(_padded(X))
+        lsd, ampd = jax.device_put(np.float32(ls)), jax.device_put(np.float32(amp))
+        n = len(X)
+        K = np.asarray(_kernel(Xd, Xd, lsd, ampd), np.float64)[:n, :n] + (
+            noise + 1e-5 * amp + 1e-8) * np.eye(n)
         L = _chol64(K)
         alpha = np.linalg.solve(L.T, np.linalg.solve(L, np.asarray(ys - p[d + 2], np.float64)))
         gp = cls(X, yn, p, L, alpha)
         gp._ymu, gp._ysd = y_mu, y_sd
+        gp._Xd, gp._lsd, gp._ampd = Xd, lsd, ampd
         return gp
 
     def predict(self, Xq: np.ndarray, return_var: bool = False):
         """Posterior mean (and variance) at Xq [Q, d] — one batched
         linear-algebra call for the whole query block, zero model waves.
+
+        The float32 cross-kernel is one device program on the query block
+        padded to a power of two (`padded` rows on the span); the mean
+        matvec and the variance solve stay float64 on the host, since
+        `_alpha` from a near-noise-free Cholesky is large and cancels.
 
         The predictive variance is clamped at a strictly positive floor
         (relative to the kernel amplitude): round-off in the Schur
@@ -153,13 +178,14 @@ class GP:
         training sets, and anything downstream that takes log/sqrt/1-over
         of the variance must stay finite."""
         Xq = np.atleast_2d(np.asarray(Xq, np.float32))
-        with tracing.span("gp.predict", points=len(Xq)):
+        Q, n = len(Xq), len(self.X)
+        Xqp = _padded(Xq, _Q_MIN)
+        with tracing.span("gp.predict", points=Q, padded=len(Xqp) - Q):
             d = self.X.shape[1]
-            ls = np.exp(self.log_params[:d])
             amp = np.exp(self.log_params[d])
             mean_c = self.log_params[d + 2]
             Ks = np.asarray(tracing.wait(
-                _matern52(jnp.asarray(Xq), jnp.asarray(self.X), jnp.asarray(ls), amp)))
+                _kernel(Xqp, self._Xd, self._lsd, self._ampd)))[:Q, :n]
             mu = mean_c + Ks @ self._alpha
             mu = self._ymu + self._ysd * mu
             if not return_var:

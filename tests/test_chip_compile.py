@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.apps.tsunami import TsunamiModel, _hvp_batch, _solve_batch, _vjp_batch
 from repro.kernels.swe import swe_step
+from repro.uq.gp import _kernel
 
 HBM_BYTES = 16e9  # device memory of one v5e chip
 
@@ -95,5 +96,15 @@ def test_hvp_batch_fits_one_chip_at_level0(one_chip):
     compiled = _hvp_batch.lower(
         _shape(one_chip, n, 2), _shape(one_chip, n, 4), _shape(one_chip, n, 2),
         TsunamiModel.N_CELLS[0], True,
+    ).compile()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+def test_gp_kernel_compiles_for_v5e(one_chip):
+    """The GP level's cross-kernel at the campaign's block: 128 queries
+    against 128 training points in two dimensions."""
+    compiled = _kernel.lower(
+        _shape(one_chip, 128, 2), _shape(one_chip, 128, 2), _shape(one_chip, 2),
+        _shape(one_chip),
     ).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES

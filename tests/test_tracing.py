@@ -186,7 +186,7 @@ def test_gp_predict_and_fused_sampler_spans(recorded):
     recs = tracing.records()
     by_id = _by_id(recs)
     (pred,) = [r for r in recs if r.name == "gp.predict"]
-    assert pred.attrs == {"points": 3}
+    assert pred.attrs == {"points": 3, "padded": 5}
     children = {r.name for r in recs if r.parent == pred.id}
     assert "device.wait" in children and children <= {"device.wait", "jax.compile"}
     (sampler,) = [r for r in recs if r.name == "sampler.fused"]

@@ -11,6 +11,7 @@ from repro.uq.distributions import (
     TruncatedNormal,
     Uniform,
 )
+from repro.uq import gp as gp_module
 from repro.uq.gp import GP
 from repro.uq.kde import kde, silverman_bandwidth
 from repro.uq.mcmc import effective_sample_size, gelman_rubin, random_walk_metropolis
@@ -327,6 +328,58 @@ def test_gp_from_params_matches_fit_factorization(rng):
     m1, v1 = gp.predict(Xq, return_var=True)
     m2, v2 = gp2.predict(Xq, return_var=True)
     np.testing.assert_allclose(v1, v2, rtol=1e-8)
+
+
+def _matern52_f64(A, B, ls, amp):
+    """The Matern-5/2 ARD kernel in float64 NumPy."""
+    d = (A[:, None, :] - B[None, :, :]) / ls
+    r2 = np.sum(d * d, axis=-1)
+    s5r = np.sqrt(5.0 * r2)
+    return amp * (1.0 + s5r + 5.0 * r2 / 3.0) * np.exp(-s5r)
+
+
+@pytest.mark.parametrize("Q,n", [(1, 6), (3, 25), (128, 128), (130, 60)])
+def test_gp_predict_matches_float64_reference(rng, Q, n):
+    """The compiled, padded kernel path against a float64 NumPy posterior
+    built from the GP's own hyperparameters and factorization; a padded
+    query returns what the same rows return inside a full power-of-two
+    block."""
+    X = rng.uniform(-1, 1, (n, 2))
+    gp = GP.from_params(X, np.sin(3 * X[:, 0]) * np.cos(2 * X[:, 1]),
+                        np.array([np.log(0.6), np.log(0.4), 0.3, np.log(1e-6), 0.1]))
+    Xq = rng.uniform(-1, 1, (Q, 2)).astype(np.float32)
+    mu, var = gp.predict(Xq, return_var=True)
+
+    p = gp.log_params
+    amp = np.exp(p[2])
+    Ks = _matern52_f64(Xq.astype(float), gp.X.astype(float), np.exp(p[:2]), amp)
+    mu_ref = gp._ymu + gp._ysd * (p[4] + Ks @ gp._alpha)
+    v = np.linalg.solve(gp._chol, Ks.T)
+    var_ref = np.maximum(amp - np.sum(v * v, axis=0), 1e-9 * amp) * gp._ysd**2
+    # float32 kernel entries are off by a few ulps of amp; the mean carries
+    # that through alpha, the variance through the triangular solve
+    eps = np.finfo(np.float32).eps * amp
+    np.testing.assert_allclose(
+        mu, mu_ref, rtol=0, atol=8 * eps * gp._ysd * np.abs(gp._alpha).sum())
+    np.testing.assert_allclose(var, var_ref, rtol=0, atol=256 * eps * gp._ysd**2)
+
+    block = max(8, 1 << (Q - 1).bit_length())
+    Xb = np.vstack([Xq, rng.uniform(-1, 1, (block - Q, 2))])
+    mu_b, var_b = gp.predict(Xb, return_var=True)
+    np.testing.assert_allclose(mu_b[:Q], mu, rtol=1e-12, atol=1e-14)
+    np.testing.assert_allclose(var_b[:Q], var, rtol=1e-12, atol=1e-14)
+
+
+def test_gp_predict_compiles_one_program_per_query_bucket(rng):
+    """Query blocks of every size from 1 to 130 rows share the six
+    power-of-two buckets 8, 16, ..., 256: one compile per bucket, never
+    one per size."""
+    X = rng.uniform(-1, 1, (20, 3))
+    gp = GP.from_params(X, X.sum(axis=1), np.array([0.0, 0.0, 0.0, 0.0, np.log(1e-4), 0.0]))
+    before = gp_module._kernel._cache_size()
+    for Q in range(1, 131):
+        assert gp.predict(rng.uniform(-1, 1, (Q, 3))).shape == (Q,)
+    assert gp_module._kernel._cache_size() - before <= 6
 
 
 # -- MCMC / MLDA ----------------------------------------------------------------
