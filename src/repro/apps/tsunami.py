@@ -14,6 +14,13 @@ drying via a depth threshold) on a 400 km ocean-to-coast transect:
     theta = (x0 [km], A [m]) — the 2-d source parameterization.
 Observables (matching the paper's GP figure): arrival time + max wave height
 at two buoys (x = 150 km, 250 km) -> 4 outputs.
+
+The 2-D sea (`GRIDS_2D`) solves the same equations on a 400 km x 400 km
+grid at the paper's smoothed resolution: 240 x 240 cells x (h, hu, hv) =
+172,800 dof, and 480 x 480 cells with the ridges at the finer level. The
+transect's profile runs along x, its shelf edge and coast bent along y;
+theta = (x0 [km], y0 [km]), the source location the paper infers, under a
+2-D Gaussian of 2.5 m and 25 km; buoys at two (x, y) sites.
 """
 from __future__ import annotations
 
@@ -21,6 +28,7 @@ import os
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from functools import lru_cache, partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +61,13 @@ T_END = 2600.0  # s
 BUOYS_KM = (150.0, 250.0)
 H_DRY = 0.05  # wetting/drying threshold [m]
 ARRIVAL_THRESH = 0.1  # m
+CFL = 0.3
+C_MAX = float(np.sqrt(G * 4100.0))  # fastest wave speed the time step allows for
+# the 2-D sea
+BUOYS_2D_KM = ((200.0, 140.0), (230.0, 270.0))  # (x, y)
+COAST_BEND_KM = 20.0  # shelf edge and coast move by +-20 km, one period over y
+SOURCE_AMP = 2.5  # m
+SOURCE_WIDTH = 25e3  # m
 
 
 def bathymetry(x: np.ndarray, smoothed: bool) -> np.ndarray:
@@ -320,6 +335,157 @@ def _hvp_batch(
     return jax.grad(directional)(thetas)
 
 
+class Grid(NamedTuple):
+    """One level of the forward model: `nx` cells along the 1-D transect, or
+    an `ny` x `nx` grid over the 2-D sea where `ny` > 0; the smoothed
+    bathymetry or the fully resolved one (ridges); `t_end` seconds
+    simulated (the 1-D transect always runs `T_END`)."""
+    nx: int
+    ny: int = 0
+    smoothed: bool = True
+    t_end: float = T_END
+
+    @property
+    def cells(self) -> int:
+        return self.nx * max(self.ny, 1)
+
+    @property
+    def dt(self) -> float:
+        dx = L_DOMAIN / self.nx
+        if self.ny:
+            dx = min(dx, L_DOMAIN / self.ny)
+        return CFL * dx / C_MAX
+
+    @property
+    def steps(self) -> int:
+        return int(self.t_end / self.dt)
+
+
+@lru_cache(maxsize=None)
+def _sea_bathymetry(nx: int, ny: int, smoothed: bool) -> np.ndarray:
+    """b(x, y) [m] on the [ny, nx] grid of the 2-D sea: the transect's
+    profile along x, shifted by COAST_BEND_KM * sin(2 pi y / L) so that the
+    shelf edge and the coast (and the fine level's ridges) bend along y."""
+    x = (np.arange(nx) + 0.5) * (L_DOMAIN / nx)
+    y = (np.arange(ny) + 0.5) * (L_DOMAIN / ny)
+    bend = COAST_BEND_KM * 1e3 * np.sin(2 * np.pi * y / L_DOMAIN)
+    return np.asarray(bathymetry(x[None, :] - bend[:, None], smoothed), np.float32)
+
+
+def _buoy_cells(grid: Grid) -> tuple:
+    """(row, column) of each 2-D buoy site on `grid`."""
+    dx, dy = L_DOMAIN / grid.nx, L_DOMAIN / grid.ny
+    return tuple((int(yk * 1e3 / dy), int(xk * 1e3 / dx)) for xk, yk in BUOYS_2D_KM)
+
+
+def _face_flux(hL, hR, bL, bR, bstar, unL, unR, utL, utR):
+    """Rusanov flux across faces whose normal points from cell L to cell R,
+    with hydrostatic reconstruction (Audusse et al.) against the face
+    bathymetry `bstar`; `un` is the velocity along the normal, `ut` across
+    it. Returns the mass flux, the normal momentum flux with its
+    well-balanced pressure correction as cell L and as cell R see it, and
+    the tangential momentum flux. Written once for both axes."""
+    hsL = jnp.maximum(hL + bL - bstar, 0.0)
+    hsR = jnp.maximum(hR + bR - bstar, 0.0)
+    mL, mR = hsL * unL, hsR * unR
+    a = jnp.maximum(jnp.abs(unL) + jnp.sqrt(G * hsL), jnp.abs(unR) + jnp.sqrt(G * hsR))
+    f_h = 0.5 * (mL + mR) - 0.5 * a * (hsR - hsL)
+    f_n = 0.5 * ((mL * unL + 0.5 * G * hsL * hsL) + (mR * unR + 0.5 * G * hsR * hsR)) \
+        - 0.5 * a * (mR - mL)
+    f_t = 0.5 * (mL * utL + mR * utR) - 0.5 * a * (hsR * utR - hsL * utL)
+    return (f_h, f_n + 0.5 * G * (hL**2 - hsL**2), f_n + 0.5 * G * (hR**2 - hsR**2), f_t)
+
+
+def _divergence(h, b, bstar, un, ut, axis: int):
+    """Per-cell flux differences along `axis` (-1: x, -2: y) of the mass,
+    the normal and the tangential momentum. The walls reflect: no mass or
+    tangential flux through them, hydrostatic pressure G/2 h^2 on them."""
+    lo, hi = slice(None, -1), slice(1, None)
+    first, last = slice(None, 1), slice(-1, None)
+
+    def cut(x, sl):
+        return x[..., sl] if axis == -1 else x[..., sl, :]
+
+    f_h, f_l, f_r, f_t = _face_flux(cut(h, lo), cut(h, hi), cut(b, lo), cut(b, hi), bstar,
+                                    cut(un, lo), cut(un, hi), cut(ut, lo), cut(ut, hi))
+
+    def walls(f_first, inner, f_last):
+        return jnp.concatenate([f_first, inner, f_last], axis)
+
+    div_h = walls(cut(f_h, first), cut(f_h, hi) - cut(f_h, lo), -cut(f_h, last))
+    div_n = walls(cut(f_l, first) - 0.5 * G * cut(h, first) ** 2, cut(f_l, hi) - cut(f_r, lo),
+                  0.5 * G * cut(h, last) ** 2 - cut(f_r, last))
+    div_t = walls(cut(f_t, first), cut(f_t, hi) - cut(f_t, lo), -cut(f_t, last))
+    return div_h, div_n, div_t
+
+
+def _sea_step(grid: Grid):
+    """The 2-D time step on [N, ny, nx] lanes of (h, hu, hv): x faces carry
+    u along their normal, y faces v; both update the state at once."""
+    b = jnp.asarray(_sea_bathymetry(grid.nx, grid.ny, grid.smoothed))
+    bstar_x = jnp.maximum(b[:, :-1], b[:, 1:])
+    bstar_y = jnp.maximum(b[:-1, :], b[1:, :])
+    lam_x, lam_y = grid.dt / (L_DOMAIN / grid.nx), grid.dt / (L_DOMAIN / grid.ny)
+
+    def step(h, hu, hv):
+        # desingularized velocities (no division blow-up at the shoreline)
+        inv = jnp.sqrt(2.0) * h / jnp.sqrt(h**4 + jnp.maximum(h, H_DRY) ** 4)
+        u, v = inv * hu, inv * hv
+        dh_x, du_x, dv_x = _divergence(h, b, bstar_x, u, v, -1)
+        dh_y, dv_y, du_y = _divergence(h, b, bstar_y, v, u, -2)
+        h_new = jnp.maximum(h - lam_x * dh_x - lam_y * dh_y, 0.0)
+        wet = h_new > H_DRY
+        hu_new = jnp.where(wet, hu - lam_x * du_x - lam_y * du_y, 0.0)
+        hv_new = jnp.where(wet, hv - lam_x * dv_x - lam_y * dv_y, 0.0)
+        return h_new, hu_new, hv_new
+
+    return step
+
+
+def _sea_source(thetas, grid: Grid):
+    """[N, 2] sources (x0, y0) [km] -> the initial depth [N, ny, nx]: still
+    water plus SOURCE_AMP * exp(-r^2 / SOURCE_WIDTH^2) where it is wet."""
+    b = jnp.asarray(_sea_bathymetry(grid.nx, grid.ny, grid.smoothed))
+    x = jnp.asarray((np.arange(grid.nx) + 0.5) * (L_DOMAIN / grid.nx), jnp.float32)
+    y = jnp.asarray((np.arange(grid.ny) + 0.5) * (L_DOMAIN / grid.ny), jnp.float32)
+    x0 = thetas[:, 0, None, None] * 1e3
+    y0 = thetas[:, 1, None, None] * 1e3
+    r2 = (x[None, None, :] - x0) ** 2 + (y[None, :, None] - y0) ** 2
+    eta0 = SOURCE_AMP * jnp.exp(-r2 / SOURCE_WIDTH**2)
+    h0 = jnp.maximum(-b, 0.0)
+    return jnp.maximum(h0 + eta0 * (h0 > H_DRY), 0.0)
+
+
+@partial(jax.jit, static_argnames=("grid",))
+def _solve_batch_2d(thetas: jax.Array, grid: Grid) -> jax.Array:
+    """[N, 2] -> [N, 4]: ONE jitted program solving N sources on the 2-D sea
+    in lockstep. State is [N, ny, nx] (x minor: the chip's lane axis holds a
+    row of the sea, whatever N is). As in `_solve_batch`, the arrival and
+    maximum at the buoys reduce inside the scan carry, so only [N, 4]
+    leaves the device."""
+    N = thetas.shape[0]
+    step = _sea_step(grid)
+    buoys = _buoy_cells(grid)
+    h = _sea_source(thetas, grid)
+    h0 = jnp.maximum(-jnp.asarray(_sea_bathymetry(grid.nx, grid.ny, grid.smoothed)), 0.0)
+    h0_buoy = jnp.stack([h0[r, c] for r, c in buoys])  # [2]
+
+    def body(carry, i):
+        h, hu, hv, mx, arr = carry
+        h, hu, hv = step(h, hu, hv)
+        eta_b = jnp.stack([h[:, r, c] for r, c in buoys], 0) - h0_buoy[:, None]  # [2, N]
+        mx = jnp.maximum(mx, eta_b)
+        arr = jnp.where((jnp.abs(eta_b) > ARRIVAL_THRESH) & (arr < 0), i, arr)
+        return (h, hu, hv, mx, arr), None
+
+    zero = jnp.zeros_like(h)
+    init = (h, zero, zero, jnp.full((2, N), -jnp.inf), jnp.full((2, N), -1.0))
+    (_, _, _, mx, arr), _ = jax.lax.scan(
+        body, init, jnp.arange(grid.steps, dtype=jnp.float32))
+    arrival = jnp.where(arr >= 0, arr * (grid.dt / 60.0), grid.t_end / 60.0)
+    return jnp.stack([arrival, mx], axis=2).transpose(1, 0, 2).reshape(N, 4)
+
+
 # Chunked dispatch for `evaluate_batch`: concurrent jitted solves on
 # power-of-2-wide chunks. Two effects stack: chunks stay cache-resident
 # ([C, <=64] working sets), and PJRT CPU executes concurrent computations on
@@ -342,6 +508,16 @@ def _chunk_executor() -> ThreadPoolExecutor:
         return _executor
 
 
+def chunk_plan(N: int, chunk_max: int = _CHUNK_MAX) -> list:
+    """[(lo, hi, bucket), ...]: the chunks of a wave of N lanes, one per
+    host core or fewer, each padded to a power-of-two `bucket` in
+    [_CHUNK_MIN, chunk_max]."""
+    workers = max(os.cpu_count() or 1, 1)
+    chunk = int(np.clip(next_pow2(-(-N // workers)), _CHUNK_MIN, chunk_max))
+    return [(lo, min(lo + chunk, N), next_pow2(max(min(chunk, N - lo), _CHUNK_MIN)))
+            for lo in range(0, N, chunk)]
+
+
 def observables(theta, n_cells: int, smoothed: bool) -> np.ndarray:
     """[arrival_1 (min), height_1 (m), arrival_2, height_2]."""
     etas, dt = _solve(jnp.asarray(theta, jnp.float32), n_cells, smoothed)
@@ -356,14 +532,20 @@ def observables(theta, n_cells: int, smoothed: bool) -> np.ndarray:
 
 
 class TsunamiModel(Model):
-    """UM-Bridge model: theta=(x0_km, amplitude_m) -> 4 observables.
+    """UM-Bridge model: theta -> 4 observables, at the levels of `grids`.
     config: {"level": 0 (coarse/smoothed, default) | 1 (fully resolved)}.
 
-    Capability-typed v2 surface: native batched evaluate AND native batched
-    gradient/apply_jacobian (lockstep AD through the SWE solver), plus the
-    fused value-and-gradient wave gradient-based samplers ride."""
+    By default the 1-D transect (`N_CELLS`), theta = (x0_km, amplitude_m),
+    with the capability-typed v2 surface: native batched evaluate AND native
+    batched gradient/apply_jacobian (lockstep AD through the SWE solver),
+    plus the fused value-and-gradient wave gradient-based samplers ride.
+    `TsunamiModel(TsunamiModel.GRIDS_2D)` is the 2-D sea, theta = (x0_km,
+    y0_km), with evaluate and evaluate_batch only."""
 
     N_CELLS = {0: 512, 1: 2048}
+    #: the 2-D sea: the paper's smoothed level (240^2 cells x 3 fields =
+    #: 172,800 dof) and a finer level with the ridges (480^2 x 3 = 691,200)
+    GRIDS_2D = {0: Grid(240, 240, smoothed=True), 1: Grid(480, 480, smoothed=False)}
     # chunks + pads internally (see evaluate_batch) — dispatcher-level
     # pow2 padding would only add wasted solves on top
     batch_bucket = False
@@ -374,15 +556,21 @@ class TsunamiModel(Model):
     #: cap on cached fused specializations (one per distinct sens_fn object)
     MAX_FUSED_CACHE = 8
 
-    def __init__(self):
+    def __init__(self, grids: dict | None = None):
         super().__init__("forward")
+        #: level -> Grid; all 1-D (the transect of `N_CELLS`) or all 2-D
+        self.grids = dict(grids) if grids is not None else {
+            lv: Grid(n, smoothed=(lv == 0)) for lv, n in self.N_CELLS.items()}
+        if len({g.ny > 0 for g in self.grids.values()}) != 1:
+            raise ValueError("a TsunamiModel's levels are all 1-D or all 2-D")
+        self.two_d = next(iter(self.grids.values())).ny > 0
         # the fabric/server dispatch waves from several threads at once
         self._lock = named_lock("tsunami.stats")
-        self.stats = {0: 0, 1: 0}
+        self.stats = {lv: 0 for lv in self.grids}
         #: per level: lanes dispatched (padding included), padded lanes and
         #: chunk programs run
         self.chunk_stats = {lv: {"lanes": 0, "padded": 0, "programs": 0}
-                            for lv in self.N_CELLS}
+                            for lv in self.grids}
         self._vgrad_cache: "OrderedDict" = OrderedDict()
 
     def get_input_sizes(self, config=None):
@@ -392,6 +580,8 @@ class TsunamiModel(Model):
         return [4]
 
     def capabilities(self, config=None) -> Capabilities:
+        if self.two_d:
+            return Capabilities(evaluate=True, evaluate_batch=True)
         return Capabilities(
             evaluate=True, evaluate_batch=True,
             gradient=True, gradient_batch=True,
@@ -399,12 +589,24 @@ class TsunamiModel(Model):
             apply_hessian=True, apply_hessian_batch=True,
         )
 
+    def _transect(self, config) -> tuple[int, bool]:
+        """(n_cells, smoothed) of a 1-D level: the derivative and per-point
+        paths exist for the transect only."""
+        if self.two_d:
+            raise NotImplementedError(
+                "the 2-D sea serves evaluate and evaluate_batch only")
+        grid = self.grids[int((config or {}).get("level", 0))]
+        return grid.nx, grid.smoothed
+
     def __call__(self, parameters, config=None):
+        if self.two_d:
+            return [list(map(float, self.evaluate_batch([parameters[0]], config)[0]))]
         level = int((config or {}).get("level", 0))
         theta = np.asarray(parameters[0], float)
         with self._lock:
             self.stats[level] += 1
-        obs = observables(theta, self.N_CELLS[level], smoothed=(level == 0))
+        n_cells, smoothed = self._transect(config)
+        obs = observables(theta, n_cells, smoothed)
         return [list(map(float, obs))]
 
     def padded_lanes(self) -> int:
@@ -419,10 +621,7 @@ class TsunamiModel(Model):
         on the host executor (see `_solve_batch` for why that beats one
         monolithic dispatch on CPU), each in a `model.chunk` span under the
         caller's span."""
-        workers = max(os.cpu_count() or 1, 1)
-        chunk = int(np.clip(next_pow2(-(-N // workers)), _CHUNK_MIN, chunk_max))
-        plan = [(lo, min(lo + chunk, N), next_pow2(max(min(chunk, N - lo), _CHUNK_MIN)))
-                for lo in range(0, N, chunk)]
+        plan = chunk_plan(N, chunk_max)
         lanes = sum(bucket for _, _, bucket in plan)
         with self._lock:
             self.stats[level] += N
@@ -431,11 +630,13 @@ class TsunamiModel(Model):
             counts["padded"] += lanes - N
             counts["programs"] += len(plan)
         parent = tracing.current()
+        grid = self.grids[level]
 
         def run(part):
             lo, hi, bucket = part
             with tracing.span("model.chunk", parent=parent, level=level,
-                              lanes=bucket, padded=bucket - (hi - lo)):
+                              lanes=bucket, padded=bucket - (hi - lo),
+                              cells=grid.cells, steps=grid.steps):
                 return solve(lo, hi, bucket)
 
         if len(plan) == 1:
@@ -443,15 +644,18 @@ class TsunamiModel(Model):
         return list(_chunk_executor().map(run, plan))
 
     def evaluate_batch(self, thetas, config=None) -> np.ndarray:
-        """[N, 2] -> [N, 4] through the lockstep batch solver, in chunks of
-        at most 64 lanes (`_map_chunks`)."""
+        """[N, 2] -> [N, 4] through the lockstep batch solver of the level's
+        grid (1-D or 2-D), in chunks of at most 64 lanes (`_map_chunks`)."""
         level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self.N_CELLS[level], (level == 0)
+        grid = self.grids[level]
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
 
         def solve(lo, hi, bucket):
-            padded, _ = pad_to_bucket(thetas[lo:hi], bucket)
-            out = _solve_batch(jnp.asarray(padded), n_cells, smoothed, _swe_impl())
+            padded = jnp.asarray(pad_to_bucket(thetas[lo:hi], bucket)[0])
+            if grid.ny:
+                out = _solve_batch_2d(padded, grid)
+            else:
+                out = _solve_batch(padded, grid.nx, grid.smoothed, _swe_impl())
             return np.asarray(tracing.wait(out), float)[: hi - lo]
 
         return np.concatenate(self._map_chunks(level, len(thetas), _CHUNK_MAX, solve), axis=0)
@@ -468,7 +672,7 @@ class TsunamiModel(Model):
         narrower than evaluate waves (reverse stores the scan carry) and
         solved concurrently on the host executor like `evaluate_batch`."""
         level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self.N_CELLS[level], (level == 0)
+        n_cells, smoothed = self._transect(config)
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
         senss = np.atleast_2d(np.asarray(senss, np.float32))
 
@@ -490,7 +694,7 @@ class TsunamiModel(Model):
     def apply_jacobian_batch(self, thetas, vecs, config=None) -> np.ndarray:
         """[N, 2] x [N, 2] -> [N, 4]: lockstep forward-mode (JVP) waves."""
         level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self.N_CELLS[level], (level == 0)
+        n_cells, smoothed = self._transect(config)
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
         vecs = np.atleast_2d(np.asarray(vecs, np.float32))
 
@@ -517,7 +721,7 @@ class TsunamiModel(Model):
         gradient waves — the reverse sweep dominates the footprint, the
         forward-mode tangents ride along at carry cost."""
         level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self.N_CELLS[level], (level == 0)
+        n_cells, smoothed = self._transect(config)
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
         senss = np.atleast_2d(np.asarray(senss, np.float32))
         vecs = np.atleast_2d(np.asarray(vecs, np.float32))
@@ -542,7 +746,7 @@ class TsunamiModel(Model):
         a transient dispatch error never silently downgrades the fused path;
         the per-sens_fn program cache is LRU-bounded."""
         level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self.N_CELLS[level], (level == 0)
+        n_cells, smoothed = self._transect(config)
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
         N = len(thetas)
         if not sens_fn_traceable(sens_fn, 4, jnp.float32):

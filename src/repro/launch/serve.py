@@ -1,7 +1,8 @@
 """UQ serving driver — the paper's deployment shape.
 
 Starts an UM-Bridge HTTP server exposing one built-in model (L2-Sea
-analogue, composite ROM, tsunami, or an LM wrapped as a UQ model); compiled
+analogue, composite ROM, the 1-D tsunami transect, the 2-D tsunami sea
+`tsunami2d`, or an LM wrapped as a UQ model); compiled
 programs persist in the compile cache (`repro.launch.compile_cache`):
 
     PYTHONPATH=src python -m repro.launch.serve --model l2sea --port 4242
@@ -34,6 +35,10 @@ def build_model(name: str, arch: str, reduced: bool):
         from repro.apps.tsunami import TsunamiModel
 
         return TsunamiModel()
+    if name == "tsunami2d":
+        from repro.apps.tsunami import TsunamiModel
+
+        return TsunamiModel(TsunamiModel.GRIDS_2D)
     if name == "lm":
         from repro.apps.lm_model import LMUQModel
 
@@ -43,7 +48,7 @@ def build_model(name: str, arch: str, reduced: bool):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", default="l2sea", choices=["l2sea", "composite", "tsunami", "lm"])
+    ap.add_argument("--model", default="l2sea", choices=["l2sea", "composite", "tsunami", "tsunami2d", "lm"])
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--port", type=int, default=4242)

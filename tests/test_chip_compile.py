@@ -19,7 +19,13 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from repro.apps.tsunami import TsunamiModel, _hvp_batch, _solve_batch, _vjp_batch
+from repro.apps.tsunami import (
+    TsunamiModel,
+    _hvp_batch,
+    _solve_batch,
+    _solve_batch_2d,
+    _vjp_batch,
+)
 from repro.kernels.swe import swe_step
 from repro.uq.gp import _kernel
 
@@ -97,6 +103,15 @@ def test_hvp_batch_fits_one_chip_at_level0(one_chip):
         _shape(one_chip, n, 2), _shape(one_chip, n, 4), _shape(one_chip, n, 2),
         TsunamiModel.N_CELLS[0], True,
     ).compile()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("level", [0, 1])
+def test_2d_sea_compiles_for_v5e_at_a_16_lane_chunk(one_chip, level):
+    """The 2-D sea's levels (240^2 smoothed, 480^2 with ridges) at the chunk
+    width a 128-point wave takes on the chip's host."""
+    compiled = _solve_batch_2d.lower(
+        _shape(one_chip, 16, 2), TsunamiModel.GRIDS_2D[level]).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
 
 

@@ -142,6 +142,8 @@ def test_chunk_spans_on_executor_threads_under_the_dispatch(recorded, monkeypatc
     assert all(c.parent == dispatch.id for c in chunks)
     assert all(c.thread.startswith("tsunami-batch") for c in chunks)
     assert sorted((c.attrs["lanes"], c.attrs["padded"]) for c in chunks) == [(4, 0), (4, 3)]
+    # a chunk's work: level 0 is 512 cells for 2,224 steps
+    assert all((c.attrs["cells"], c.attrs["steps"]) == (512, 2224) for c in chunks)
     assert dispatch.attrs == {"op": "evaluate", "lanes": 5}
     waits = [r for r in recs if r.name == "device.wait"]
     assert sorted(w.parent for w in waits) == sorted(c.id for c in chunks)
