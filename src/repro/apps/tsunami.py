@@ -43,7 +43,9 @@ from repro.core.interface import (
     pad_to_bucket,
     sens_fn_traceable,
 )
+from repro.kernels import swe2d
 from repro.kernels.swe import swe_step
+from repro.kernels.swe2d.swe2d import G, H_DRY, face_flux, update, velocities
 
 
 def _swe_impl() -> str:
@@ -55,11 +57,9 @@ def _swe_impl() -> str:
     `_sqrt_safe` clamped adjoint anyway."""
     return os.environ.get("REPRO_SWE_KERNEL", "scan")
 
-G = 9.81
 L_DOMAIN = 400e3  # m
 T_END = 2600.0  # s
 BUOYS_KM = (150.0, 250.0)
-H_DRY = 0.05  # wetting/drying threshold [m]
 ARRIVAL_THRESH = 0.1  # m
 CFL = 0.3
 C_MAX = float(np.sqrt(G * 4100.0))  # fastest wave speed the time step allows for
@@ -372,28 +372,15 @@ def _sea_bathymetry(nx: int, ny: int, smoothed: bool) -> np.ndarray:
     return np.asarray(bathymetry(x[None, :] - bend[:, None], smoothed), np.float32)
 
 
+def _courant(grid: Grid) -> tuple:
+    """(dt / dx, dt / dy) of a 2-D grid."""
+    return grid.dt / (L_DOMAIN / grid.nx), grid.dt / (L_DOMAIN / grid.ny)
+
+
 def _buoy_cells(grid: Grid) -> tuple:
     """(row, column) of each 2-D buoy site on `grid`."""
     dx, dy = L_DOMAIN / grid.nx, L_DOMAIN / grid.ny
     return tuple((int(yk * 1e3 / dy), int(xk * 1e3 / dx)) for xk, yk in BUOYS_2D_KM)
-
-
-def _face_flux(hL, hR, bL, bR, bstar, unL, unR, utL, utR):
-    """Rusanov flux across faces whose normal points from cell L to cell R,
-    with hydrostatic reconstruction (Audusse et al.) against the face
-    bathymetry `bstar`; `un` is the velocity along the normal, `ut` across
-    it. Returns the mass flux, the normal momentum flux with its
-    well-balanced pressure correction as cell L and as cell R see it, and
-    the tangential momentum flux. Written once for both axes."""
-    hsL = jnp.maximum(hL + bL - bstar, 0.0)
-    hsR = jnp.maximum(hR + bR - bstar, 0.0)
-    mL, mR = hsL * unL, hsR * unR
-    a = jnp.maximum(jnp.abs(unL) + jnp.sqrt(G * hsL), jnp.abs(unR) + jnp.sqrt(G * hsR))
-    f_h = 0.5 * (mL + mR) - 0.5 * a * (hsR - hsL)
-    f_n = 0.5 * ((mL * unL + 0.5 * G * hsL * hsL) + (mR * unR + 0.5 * G * hsR * hsR)) \
-        - 0.5 * a * (mR - mL)
-    f_t = 0.5 * (mL * utL + mR * utR) - 0.5 * a * (hsR * utR - hsL * utL)
-    return (f_h, f_n + 0.5 * G * (hL**2 - hsL**2), f_n + 0.5 * G * (hR**2 - hsR**2), f_t)
 
 
 def _divergence(h, b, bstar, un, ut, axis: int):
@@ -406,8 +393,8 @@ def _divergence(h, b, bstar, un, ut, axis: int):
     def cut(x, sl):
         return x[..., sl] if axis == -1 else x[..., sl, :]
 
-    f_h, f_l, f_r, f_t = _face_flux(cut(h, lo), cut(h, hi), cut(b, lo), cut(b, hi), bstar,
-                                    cut(un, lo), cut(un, hi), cut(ut, lo), cut(ut, hi))
+    f_h, f_l, f_r, f_t = face_flux(cut(h, lo), cut(h, hi), cut(b, lo), cut(b, hi), bstar,
+                                   cut(un, lo), cut(un, hi), cut(ut, lo), cut(ut, hi))
 
     def walls(f_first, inner, f_last):
         return jnp.concatenate([f_first, inner, f_last], axis)
@@ -425,19 +412,13 @@ def _sea_step(grid: Grid):
     b = jnp.asarray(_sea_bathymetry(grid.nx, grid.ny, grid.smoothed))
     bstar_x = jnp.maximum(b[:, :-1], b[:, 1:])
     bstar_y = jnp.maximum(b[:-1, :], b[1:, :])
-    lam_x, lam_y = grid.dt / (L_DOMAIN / grid.nx), grid.dt / (L_DOMAIN / grid.ny)
+    lam_x, lam_y = _courant(grid)
 
     def step(h, hu, hv):
-        # desingularized velocities (no division blow-up at the shoreline)
-        inv = jnp.sqrt(2.0) * h / jnp.sqrt(h**4 + jnp.maximum(h, H_DRY) ** 4)
-        u, v = inv * hu, inv * hv
+        u, v = velocities(h, hu, hv)
         dh_x, du_x, dv_x = _divergence(h, b, bstar_x, u, v, -1)
         dh_y, dv_y, du_y = _divergence(h, b, bstar_y, v, u, -2)
-        h_new = jnp.maximum(h - lam_x * dh_x - lam_y * dh_y, 0.0)
-        wet = h_new > H_DRY
-        hu_new = jnp.where(wet, hu - lam_x * du_x - lam_y * du_y, 0.0)
-        hv_new = jnp.where(wet, hv - lam_x * dv_x - lam_y * dv_y, 0.0)
-        return h_new, hu_new, hv_new
+        return update(h, hu, hv, (dh_x, du_x, dv_x), (dh_y, du_y, dv_y), lam_x, lam_y)
 
     return step
 
@@ -459,14 +440,36 @@ def _sea_source(thetas, grid: Grid):
 @partial(jax.jit, static_argnames=("grid",))
 def _solve_batch_2d(thetas: jax.Array, grid: Grid) -> jax.Array:
     """[N, 2] -> [N, 4]: ONE jitted program solving N sources on the 2-D sea
-    in lockstep. State is [N, ny, nx] (x minor: the chip's lane axis holds a
-    row of the sea, whatever N is). As in `_solve_batch`, the arrival and
-    maximum at the buoys reduce inside the scan carry, so only [N, 4]
-    leaves the device."""
+    in lockstep, along the path `swe2d.sea_path` picks for the platform and
+    the grid: on a TPU the whole time loop in one Pallas kernel that keeps
+    each lane's state in VMEM, elsewhere the jnp scan of `_sea_step`."""
+    return _sea_observables(thetas, grid, swe2d.sea_path(grid.ny, grid.nx))
+
+
+def _sea_observables(thetas: jax.Array, grid: Grid, path: str) -> jax.Array:
+    """`_solve_batch_2d` along `path` ("pallas" or "xla"). Either way the
+    arrival and maximum at the buoys reduce inside the time loop, so only
+    [N, 4] leaves the device."""
     N = thetas.shape[0]
-    step = _sea_step(grid)
-    buoys = _buoy_cells(grid)
     h = _sea_source(thetas, grid)
+    buoys = _buoy_cells(grid)
+    if path == "pallas":
+        lam_x, lam_y = _courant(grid)
+        mx, arr = swe2d.sea_solve(h, _sea_bathymetry(grid.nx, grid.ny, grid.smoothed),
+                                  steps=grid.steps, lam_x=lam_x, lam_y=lam_y, buoys=buoys,
+                                  thresh=ARRIVAL_THRESH)
+    else:
+        mx, arr = _sea_scan(h, grid, buoys)
+    arrival = jnp.where(arr >= 0, arr * (grid.dt / 60.0), grid.t_end / 60.0)
+    return jnp.stack([arrival, mx], axis=2).transpose(1, 0, 2).reshape(N, 4)
+
+
+def _sea_scan(h, grid: Grid, buoys: tuple):
+    """The time loop as a jnp scan of `_sea_step` from the depth h [N, ny,
+    nx] (x minor: the lane axis holds a row of the sea, whatever N is):
+    (max elevation, first step above the threshold), each [buoys, N]."""
+    N = h.shape[0]
+    step = _sea_step(grid)
     h0 = jnp.maximum(-jnp.asarray(_sea_bathymetry(grid.nx, grid.ny, grid.smoothed)), 0.0)
     h0_buoy = jnp.stack([h0[r, c] for r, c in buoys])  # [2]
 
@@ -482,8 +485,7 @@ def _solve_batch_2d(thetas: jax.Array, grid: Grid) -> jax.Array:
     init = (h, zero, zero, jnp.full((2, N), -jnp.inf), jnp.full((2, N), -1.0))
     (_, _, _, mx, arr), _ = jax.lax.scan(
         body, init, jnp.arange(grid.steps, dtype=jnp.float32))
-    arrival = jnp.where(arr >= 0, arr * (grid.dt / 60.0), grid.t_end / 60.0)
-    return jnp.stack([arrival, mx], axis=2).transpose(1, 0, 2).reshape(N, 4)
+    return mx, arr
 
 
 # Chunked dispatch for `evaluate_batch`: concurrent jitted solves on
@@ -614,13 +616,14 @@ class TsunamiModel(Model):
         with self._lock:
             return sum(c["padded"] for c in self.chunk_stats.values())
 
-    def _map_chunks(self, level: int, N: int, chunk_max: int, solve) -> list:
+    def _map_chunks(self, level: int, N: int, chunk_max: int, solve, kernel: str = "xla") -> list:
         """[solve(lo, hi, bucket), ...] over the chunks of a wave of N lanes:
         lanes [lo, hi) padded to `bucket`, a power of two, so the jit cache
         holds a handful of shapes per level. Several chunks run CONCURRENTLY
         on the host executor (see `_solve_batch` for why that beats one
         monolithic dispatch on CPU), each in a `model.chunk` span under the
-        caller's span."""
+        caller's span that names the path `solve` takes (`kernel`: "pallas"
+        or "xla")."""
         plan = chunk_plan(N, chunk_max)
         lanes = sum(bucket for _, _, bucket in plan)
         with self._lock:
@@ -636,7 +639,7 @@ class TsunamiModel(Model):
             lo, hi, bucket = part
             with tracing.span("model.chunk", parent=parent, level=level,
                               lanes=bucket, padded=bucket - (hi - lo),
-                              cells=grid.cells, steps=grid.steps):
+                              cells=grid.cells, steps=grid.steps, kernel=kernel):
                 return solve(lo, hi, bucket)
 
         if len(plan) == 1:
@@ -649,6 +652,10 @@ class TsunamiModel(Model):
         level = int((config or {}).get("level", 0))
         grid = self.grids[level]
         thetas = np.atleast_2d(np.asarray(thetas, np.float32))
+        if grid.ny:
+            kernel = swe2d.sea_path(grid.ny, grid.nx)
+        else:
+            kernel = "xla" if _swe_impl() in ("scan", "ref") else "pallas"
 
         def solve(lo, hi, bucket):
             padded = jnp.asarray(pad_to_bucket(thetas[lo:hi], bucket)[0])
@@ -658,7 +665,8 @@ class TsunamiModel(Model):
                 out = _solve_batch(padded, grid.nx, grid.smoothed, _swe_impl())
             return np.asarray(tracing.wait(out), float)[: hi - lo]
 
-        return np.concatenate(self._map_chunks(level, len(thetas), _CHUNK_MAX, solve), axis=0)
+        return np.concatenate(
+            self._map_chunks(level, len(thetas), _CHUNK_MAX, solve, kernel), axis=0)
 
     # -- batched derivative surface -----------------------------------------
     def gradient(self, out_wrt, in_wrt, parameters, sens, config=None):

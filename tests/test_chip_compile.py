@@ -19,6 +19,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro.apps import tsunami as ts
 from repro.apps.tsunami import (
     TsunamiModel,
     _hvp_batch,
@@ -27,6 +28,8 @@ from repro.apps.tsunami import (
     _vjp_batch,
 )
 from repro.kernels.swe import swe_step
+from repro.kernels.swe2d import ops as swe2d_ops
+from repro.kernels.swe2d import sea_kernel
 from repro.uq.gp import _kernel
 
 HBM_BYTES = 16e9  # device memory of one v5e chip
@@ -113,6 +116,21 @@ def test_2d_sea_compiles_for_v5e_at_a_16_lane_chunk(one_chip, level):
     compiled = _solve_batch_2d.lower(
         _shape(one_chip, 16, 2), TsunamiModel.GRIDS_2D[level]).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("level", [0, 1])
+def test_2d_sea_kernel_compiles_for_v5e_at_a_16_lane_chunk(one_chip, level):
+    """The time-loop kernel the chip runs for each 2-D level, with its
+    padded width, the strip height of its shape and its VMEM request."""
+    grid = TsunamiModel.GRIDS_2D[level]
+    w = swe2d_ops.padded_width(grid.nx)
+    lam_x, lam_y = ts._courant(grid)
+    run = partial(sea_kernel, nx=grid.nx, strip=swe2d_ops.strip_rows(grid.ny, w),
+                  steps=grid.steps, lam_x=lam_x, lam_y=lam_y, buoys=ts._buoy_cells(grid),
+                  thresh=ts.ARRIVAL_THRESH)
+    bath = _shape(one_chip, grid.ny + 8, w)
+    compiled = jax.jit(run).lower(_shape(one_chip, 16, grid.ny, w), bath, bath, bath).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_gp_kernel_compiles_for_v5e(one_chip):

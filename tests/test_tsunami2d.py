@@ -1,7 +1,9 @@
 """The 2-D tsunami sea (`TsunamiModel(grids)` with 2-D `Grid`s) at small
 grids: the served path against the plain reference of
 `repro.kernels.swe.ref`, lake at rest, conservation of mass, padding, the
-capability surface, the chunk spans and the sizes of the deployed levels."""
+capability surface, the chunk spans and the sizes of the deployed levels;
+and the time-loop kernel of `repro.kernels.swe2d` (interpret mode) against
+the jnp step it shares its physics with."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,8 @@ from repro.core import tracing
 from repro.core.fabric import EvaluationFabric, ModelBackend
 from repro.core.interface import pad_to_bucket
 from repro.kernels.swe import swe2d_simulate_ref
+from repro.kernels.swe2d import ops as swe2d_ops
+from repro.kernels.swe2d import sea_kernel, sea_path, sea_solve
 
 #: small levels of the sea: the smoothed bathymetry and the ridged one
 SMALL = {0: Grid(32, 32, smoothed=True, t_end=2000.0),
@@ -20,6 +24,10 @@ SMALL = {0: Grid(32, 32, smoothed=True, t_end=2000.0),
 #: one unit in the last place of float32 at the deepest still water (4 km):
 #: the resolution of the elevation, which is depth minus still depth
 DEPTH_ULP = float(np.spacing(np.float32(4000.0)))
+#: small seas for the kernel: nx not a multiple of 128 (padded lanes), a dry
+#: coast in the last columns, one strip or several
+KERNEL_SEAS = {"smoothed": Grid(40, 32, smoothed=True, t_end=2000.0),
+               "ridged": Grid(72, 48, smoothed=False, t_end=2000.0)}
 
 
 def _thetas(n=4, seed=7):
@@ -162,3 +170,113 @@ def test_serve_builds_the_2d_sea():
 
     model = build_model("tsunami2d", "", False)
     assert model.two_d and model.grids == TsunamiModel.GRIDS_2D
+
+
+def _kernel_run(grid, h, steps, strip=None):
+    """(h, hu, hv) after `steps` kernel steps from the depth h at rest."""
+    lam_x, lam_y = ts._courant(grid)
+    *_, state = sea_solve(h, ts._sea_bathymetry(grid.nx, grid.ny, grid.smoothed), steps=steps,
+                          lam_x=lam_x, lam_y=lam_y, buoys=ts._buoy_cells(grid),
+                          thresh=ts.ARRIVAL_THRESH, strip=strip, keep_state=True)
+    return state
+
+
+def _scan_run(grid, h, steps):
+    step = jax.jit(ts._sea_step(grid))
+    state = h, jnp.zeros_like(h), jnp.zeros_like(h)
+    for _ in range(steps):
+        state = step(*state)
+    return state
+
+
+def _momentum_rounding(grid, steps):
+    """How far float32 reassociation can move momentum in `steps` steps: a
+    step adds dt/dx times the rounding of the hydrostatic pressure g/2 h^2
+    (a unit in the last place at 4 km depth) along each axis."""
+    lam_x, lam_y = ts._courant(grid)
+    return steps * (lam_x + lam_y) * float(np.spacing(np.float32(0.5 * ts.G * 4000.0**2)))
+
+
+@pytest.mark.parametrize("strip", [8, None], ids=["strip8", "strip_of_shape"])
+@pytest.mark.parametrize("sea", sorted(KERNEL_SEAS))
+def test_kernel_state_matches_the_jnp_step(sea, strip):
+    grid, steps = KERNEL_SEAS[sea], 30
+    h = ts._sea_source(jnp.asarray(_thetas(3)), grid)
+    got = _kernel_run(grid, h, steps, strip)
+    want = _scan_run(grid, h, steps)
+    # the same operations in another fusion: float32 reassociation only
+    np.testing.assert_allclose(got[0], want[0], rtol=0, atol=4 * DEPTH_ULP)
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(g, w, rtol=0, atol=_momentum_rounding(grid, steps))
+    assert float(jnp.max(jnp.abs(want[1]))) > 1.0  # the water moved
+
+
+@pytest.mark.parametrize("sea", sorted(KERNEL_SEAS))
+def test_kernel_observables_match_the_jnp_path(sea):
+    grid = KERNEL_SEAS[sea]
+    thetas = jnp.asarray(_thetas(3))
+    solve = jax.jit(ts._sea_observables, static_argnames=("grid", "path"))
+    got = np.asarray(solve(thetas, grid, "pallas"))
+    want = np.asarray(solve(thetas, grid, "xla"))
+    np.testing.assert_allclose(got[:, 1::2], want[:, 1::2], rtol=0, atol=4 * DEPTH_ULP)
+    np.testing.assert_allclose(got[:, ::2], want[:, ::2], rtol=0, atol=grid.dt / 60 + 1e-5)
+    assert np.any(want[:, ::2] < grid.t_end / 60)  # the waves reach the buoys
+
+
+@pytest.mark.parametrize("sea", sorted(KERNEL_SEAS))
+def test_kernel_keeps_a_lake_at_rest(sea):
+    grid = KERNEL_SEAS[sea]
+    b = ts._sea_bathymetry(grid.nx, grid.ny, grid.smoothed)
+    still = jnp.asarray(np.maximum(-b, 0.0))[None]
+    h, hu, hv = _kernel_run(grid, still, grid.steps)
+    assert float(jnp.max(jnp.abs(h - still))) <= 2 * DEPTH_ULP
+    assert float(jnp.max(jnp.abs(hu))) < 1.0 and float(jnp.max(jnp.abs(hv))) < 1.0
+
+
+@pytest.mark.parametrize("sea", sorted(KERNEL_SEAS))
+def test_kernel_conserves_mass_between_the_walls(sea):
+    grid = KERNEL_SEAS[sea]
+    h0 = ts._sea_source(jnp.asarray(_thetas(2)), grid)
+    h, hu, _ = _kernel_run(grid, h0, grid.steps, strip=8)
+    np.testing.assert_allclose(np.asarray(h, np.float64).sum((1, 2)),
+                               np.asarray(h0, np.float64).sum((1, 2)), rtol=1e-6)
+    assert float(jnp.max(jnp.abs(hu))) > 1.0
+
+
+@pytest.mark.parametrize("sea", sorted(KERNEL_SEAS))
+def test_kernel_padded_columns_stay_inert(sea):
+    grid = KERNEL_SEAS[sea]
+    h0, *bath = swe2d_ops.padded(ts._sea_source(jnp.asarray(_thetas(2)), grid),
+                                 ts._sea_bathymetry(grid.nx, grid.ny, grid.smoothed))
+    assert h0.shape[-1] % 128 == 0 and h0.shape[-1] > grid.nx
+    lam_x, lam_y = ts._courant(grid)
+    _, state = sea_kernel(h0, *bath, nx=grid.nx, strip=8, steps=grid.steps, lam_x=lam_x,
+                          lam_y=lam_y, buoys=ts._buoy_cells(grid), thresh=ts.ARRIVAL_THRESH,
+                          keep_state=True, interpret=True)
+    state = np.asarray(state)
+    np.testing.assert_array_equal(state[..., grid.nx:], 0.0)
+    assert np.abs(state[:, 1:, :, :grid.nx]).max() > 1.0  # next to them the water moved
+
+
+def test_cpu_takes_the_jnp_path_and_the_chunk_span_names_it():
+    grid = SMALL[0]
+    assert jax.default_backend() == "cpu" and sea_path(grid.ny, grid.nx) == "xla"
+    solve = jax.jit(ts._sea_observables, static_argnames=("grid", "path"))
+    thetas = _thetas(2)
+    np.testing.assert_array_equal(np.asarray(ts._solve_batch_2d(jnp.asarray(thetas), grid)),
+                                  np.asarray(solve(jnp.asarray(thetas), grid, "xla")))
+    tracing.clear()
+    with tracing.recording():
+        TsunamiModel(SMALL).evaluate_batch(thetas, {"level": 0})
+        TsunamiModel().evaluate_batch(_thetas(1), {"level": 0})
+    chunks = [r for r in tracing.records() if r.name == "model.chunk"]
+    tracing.clear()
+    assert {c.attrs["cells"] for c in chunks} == {32 * 32, 512}
+    assert all(c.attrs["kernel"] == "xla" for c in chunks)
+
+
+@pytest.mark.parametrize("level,width,strip", [(0, 256, 48), (1, 512, 32)])
+def test_strips_of_the_deployed_levels_tile_their_rows(level, width, strip):
+    grid = TsunamiModel.GRIDS_2D[level]
+    assert swe2d_ops.padded_width(grid.nx) == width
+    assert swe2d_ops.strip_rows(grid.ny, width) == strip and grid.ny % strip == 0
