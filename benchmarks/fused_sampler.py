@@ -1,6 +1,6 @@
 """Fused sampler blocks: steps/s per-step vs device-resident (`uq.fused`).
 
-Three measurements, one per claim:
+Two measurements, one per claim:
 
 1. **Gaussian posterior** — the target costs a handful of FLOPs, so steps/s
    is a pure measurement of sampler-loop dispatch economics. Two
@@ -16,10 +16,6 @@ Three measurements, one per claim:
    DISPATCH-bound (at the paper's 512-cell coarse level the solve itself
    dominates and no loop restructuring can win 10x; the fused win there is
    the removed per-step latency floor, not wall-clock compute).
-3. **SWE stencil microbench** — one `kernels.swe` Rusanov step: jitted
-   inline scan math vs the Pallas kernel (interpret mode on CPU — an
-   emulation, so its µs/step is a correctness artifact, not TPU perf) plus
-   the parity error against the jitted reference.
 
     PYTHONPATH=src python -m benchmarks.fused_sampler [--smoke] [--json PATH]
 
@@ -102,48 +98,6 @@ def _bench_posterior(logpost_dev, loglik_host, x0s, prop_cov, *,
     }
 
 
-def _bench_swe_stencil(reps: int) -> dict:
-    """One Rusanov step on a [512, 64] tile: jitted scan math vs kernel."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.swe.ops import swe_step
-    from repro.kernels.swe.ref import swe_step_ref
-
-    C, N = 512, 64
-    rng = np.random.default_rng(0)
-    x = np.linspace(0.0, 1.0, C)[:, None]
-    b = jnp.asarray(0.1 * np.sin(3 * np.pi * x))
-    h = jnp.asarray(0.7 + 0.2 * rng.random((C, N)))
-    hu = jnp.asarray(0.05 * rng.standard_normal((C, N)))
-
-    jref = jax.jit(lambda a, q, bb: swe_step_ref(a, q, bb, 0.02))
-    kern = partial(swe_step, dt_dx=0.02, impl="interpret")
-
-    def rate(fn):
-        jax.block_until_ready(fn(h, hu, b))
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn(h, hu, b)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / reps * 1e6
-
-    us_ref = rate(jref)
-    us_kernel = rate(kern)
-    rh, rhu = jref(h, hu, b)
-    kh, khu = kern(h, hu, b)
-    err = max(float(jnp.max(jnp.abs(kh - rh))), float(jnp.max(jnp.abs(khu - rhu))))
-    return {
-        "cells": C, "batch": N,
-        "ref_us_per_step": us_ref,
-        "kernel_interpret_us_per_step": us_kernel,
-        "max_abs_err_vs_jitted_ref": err,
-        # interpret mode emulates the TPU kernel op-by-op on CPU — its
-        # timing is for the record, the parity number is the point here
-        "note": "interpret-mode timing; pallas path targets TPU",
-    }
-
-
 def main(quick: bool = True, smoke: bool = False) -> dict:
     import jax.numpy as jnp
 
@@ -151,11 +105,11 @@ def main(quick: bool = True, smoke: bool = False) -> dict:
     from repro.uq.fused import gaussian_likelihood_target, gaussian_target
 
     if smoke:
-        S, n_big, n_host, reps = 50, 500, 100, 3
+        S, n_big, n_host = 50, 500, 100
     elif quick:
-        S, n_big, n_host, reps = 200, 4000, 500, 10
+        S, n_big, n_host = 200, 4000, 500
     else:
-        S, n_big, n_host, reps = 500, 20000, 2000, 30
+        S, n_big, n_host = 500, 20000, 2000
 
     # -- 1: Gaussian (dispatch economics in isolation) ------------------------
     d, K = 4, 8
@@ -186,16 +140,12 @@ def main(quick: bool = True, smoke: bool = False) -> dict:
         fused_steps=S, n_big=max(n_big // 2, S), n_host=n_host)
     tsunami["n_cells"] = n_cells
 
-    # -- 3: SWE stencil microbench ---------------------------------------------
-    stencil = _bench_swe_stencil(reps)
-
     doc = {
         "schema": "repro-fused-sampler-v1",
         "mode": "smoke" if smoke else ("quick" if quick else "full"),
         "chains": K,
         "gaussian": gauss,
         "tsunami_coarse": tsunami,
-        "swe_stencil": stencil,
     }
     print(
         f"fused sampler: gaussian {gauss['fused_steps_per_sec']:.0f} steps/s "
@@ -203,8 +153,7 @@ def main(quick: bool = True, smoke: bool = False) -> dict:
         f"{gauss['speedup_vs_host_fabric']:.1f}x vs host+fabric); "
         f"tsunami[{n_cells} cells] {tsunami['fused_steps_per_sec']:.0f} steps/s "
         f"({tsunami['speedup_vs_per_step']:.1f}x vs per-step, "
-        f"{tsunami['speedup_vs_host_fabric']:.1f}x vs host+fabric); "
-        f"stencil parity err {stencil['max_abs_err_vs_jitted_ref']:.1e}"
+        f"{tsunami['speedup_vs_host_fabric']:.1f}x vs host+fabric)"
     )
     return doc
 
@@ -231,10 +180,6 @@ def _cli():
             raise SystemExit(
                 f"{name}: fused speedup {got:.1f}x below the {floor}x floor "
                 f"— the fused block is not amortizing dispatch")
-    if doc["swe_stencil"]["max_abs_err_vs_jitted_ref"] != 0.0:
-        raise SystemExit(
-            "swe stencil kernel drifted from the jitted reference "
-            f"({doc['swe_stencil']['max_abs_err_vs_jitted_ref']:.3e})")
 
 
 if __name__ == "__main__":

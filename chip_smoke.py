@@ -9,12 +9,13 @@ Phases, each checked against a reference, at the model's full width (level 0:
 
 * served: `TsunamiModel` behind the UM-Bridge HTTP server, driven by the
   `HTTPModel` client with evaluate waves at both levels, a gradient wave and
-  a Hessian-vector wave. Evaluate rows must match the per-point reference
-  `observables()` run on the host CPU.
+  a Hessian-vector wave. Evaluate rows must match the plain per-source
+  reference `swe_simulate_ref`, run on the host CPU and reduced there.
 * campaign: `ensemble_mlda` through `EvaluationFabric(ModelBackend(...))`,
   and one fused device-resident RWM block over the level-0 batch solver.
-* kernel: the Pallas SWE stencil (`swe_impl="pallas"`) against the scan path;
-  its lowering must hold `tpu_custom_call`, so it is not interpret mode.
+* kernel: the 2-D sea's time-loop Pallas kernel (`kernels/swe2d`) against
+  its jnp scan on a 16-lane chunk of the smoothed level; its lowering must
+  hold `tpu_custom_call`, so it is not interpret mode.
 * sharded (`--chips 4`): a `ModelPool` wave and a fused chain block over a
   4x1 `data` mesh must equal their one-device results.
 
@@ -100,7 +101,7 @@ def served_phase(rng, n_eval: int = 64, n_grad: int = 16, n_hvp: int = 16,
     per-point reference on the host CPU."""
     import jax
 
-    from repro.apps.tsunami import TsunamiModel, observables
+    from repro.apps.tsunami import TsunamiModel
     from repro.core.client import HTTPModel
     from repro.core.server import serve_models
 
@@ -139,10 +140,36 @@ def served_phase(rng, n_eval: int = 64, n_grad: int = 16, n_hvp: int = 16,
 
     with jax.default_device(jax.devices("cpu")[0]):
         for level, (thetas, ys) in evals.items():
-            n_cells = TsunamiModel.N_CELLS[level]
-            ref = np.array([observables(t, n_cells, level == 0) for t in thetas[:n_ref]])
+            ref = np.array([_reference(t, model.grids[level]) for t in thetas[:n_ref]])
             info[f"reference_l{level}"] = _compare(ys[:n_ref], ref, level)
     return info
+
+
+def _reference(theta: np.ndarray, grid) -> np.ndarray:
+    """[4] observables of one 1-D source by the plain reference: the buoys'
+    elevation after every step (`swe_simulate_ref`), then the first step
+    above the threshold and the largest elevation, on the host."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.apps import tsunami as ts
+    from repro.kernels.swe import swe_simulate_ref
+
+    dx = ts.L_DOMAIN / grid.nx
+    x = (np.arange(grid.nx) + 0.5) * dx
+    b = ts.bathymetry(x, grid.smoothed).astype(np.float32)
+    h0 = np.maximum(-b, 0.0)
+    eta0 = theta[1] * np.exp(-(((x - theta[0] * 1e3) / 25e3) ** 2))
+    h = np.maximum(h0 + eta0 * (h0 > ts.H_DRY), 0.0).astype(np.float32)
+    rows = tuple(int(bk * 1e3 / dx) for bk in ts.BUOYS_KM)
+    etas = np.asarray(jax.jit(swe_simulate_ref, static_argnums=(2, 3, 4))(
+        jnp.asarray(h), jnp.asarray(b), grid.dt / dx, grid.steps, rows))
+    out = []
+    for j in range(etas.shape[1]):
+        above = np.abs(etas[:, j]) > ts.ARRIVAL_THRESH
+        out += [np.argmax(above) * grid.dt / 60 if above.any() else grid.t_end / 60,
+                etas[:, j].max()]
+    return np.array(out)
 
 
 def campaign_phase(rng, n_chains: int = 64, n_fine: int = 3, n_sub: int = 4,
@@ -208,31 +235,34 @@ def campaign_phase(rng, n_chains: int = 64, n_fine: int = 3, n_sub: int = 4,
     return info
 
 
-def kernel_phase(rng) -> dict:
-    """Pallas SWE stencil against the scan path on full-width waves."""
+def kernel_phase(rng, lanes: int = 16) -> dict:
+    """The 2-D sea's Pallas time-loop kernel against its jnp scan on one
+    chunk of the smoothed level, as a 128-point wave cuts it on the chip's
+    host."""
+    import jax
     import jax.numpy as jnp
 
-    from repro.apps.tsunami import TsunamiModel, _solve_batch
+    from repro.apps.tsunami import TsunamiModel, _sea_observables
 
-    info = {}
-    for level, lanes in ((0, 64), (1, 128)):
-        n_cells, smoothed = TsunamiModel.N_CELLS[level], level == 0
-        thetas = jnp.asarray(_points(rng, lanes), jnp.float32)
-        hlo = _solve_batch.lower(thetas, n_cells, smoothed, "pallas").as_text()
-        if "tpu_custom_call" not in hlo:
-            raise AssertionError(f"level {level}: Pallas lowering holds no tpu_custom_call")
-        outs = {}
-        for impl in ("pallas", "scan"):
-            t0 = time.perf_counter()
-            outs[impl] = np.asarray(_solve_batch(thetas, n_cells, smoothed, impl), float)
-            first = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            np.asarray(_solve_batch(thetas, n_cells, smoothed, impl))
-            info[f"{impl}_l{level}_x{lanes}_s"] = {
-                "first_call": first, "second_call": time.perf_counter() - t0,
-            }
-        _check_finite_nonzero(f"pallas level {level}", outs["pallas"], (lanes, 4))
-        info[f"pallas_vs_scan_l{level}"] = _compare(outs["pallas"], outs["scan"], level)
+    grid = TsunamiModel.GRIDS_2D[0]
+    thetas = jnp.asarray(np.stack([rng.uniform(40.0, 140.0, lanes),
+                                   rng.uniform(120.0, 280.0, lanes)], axis=1), jnp.float32)
+    runs = {path: jax.jit(partial(_sea_observables, grid=grid, path=path))
+            for path in ("pallas", "xla")}
+    if "tpu_custom_call" not in runs["pallas"].lower(thetas).as_text():
+        raise AssertionError("the 2-D Pallas lowering holds no tpu_custom_call")
+    info, outs = {}, {}
+    for path, run in runs.items():
+        t0 = time.perf_counter()
+        outs[path] = np.asarray(run(thetas), float)
+        first = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        np.asarray(run(thetas))
+        info[f"{path}_2d_l0_x{lanes}_s"] = {
+            "first_call": first, "second_call": time.perf_counter() - t0,
+        }
+    _check_finite_nonzero("pallas 2-D level 0", outs["pallas"], (lanes, 4))
+    info["pallas_vs_xla_2d_l0"] = _compare(outs["pallas"], outs["xla"], 0)
     return info
 
 

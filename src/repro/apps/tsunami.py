@@ -44,18 +44,8 @@ from repro.core.interface import (
     sens_fn_traceable,
 )
 from repro.kernels import swe2d
-from repro.kernels.swe import swe_step
 from repro.kernels.swe2d.swe2d import G, H_DRY, face_flux, update, velocities
 
-
-def _swe_impl() -> str:
-    """SWE stencil implementation for forward evaluate waves: "scan"
-    (default: the inline jnp scan body below), or "pallas"/"interpret"/"ref"
-    to route the flux+limiter+update stencil through the fused
-    `repro.kernels.swe` kernel. Derivative waves always use the inline scan
-    body — `pl.pallas_call` is forward-only here, and the VJP needs the
-    `_sqrt_safe` clamped adjoint anyway."""
-    return os.environ.get("REPRO_SWE_KERNEL", "scan")
 
 L_DOMAIN = 400e3  # m
 T_END = 2600.0  # s
@@ -111,107 +101,80 @@ def _sqrt_safe_jvp(primals, tangents):
 
 @lru_cache(maxsize=None)
 def _bathymetry_cached(n_cells: int, smoothed: bool) -> np.ndarray:
-    """b(x) on the n_cells grid, computed once per (n_cells, smoothed) —
-    the host-numpy transcendentals here used to be recomputed on every
-    trace of `_solve` (and every vmap lane of the batch program)."""
+    """b(x) on the n_cells grid, computed once per (n_cells, smoothed) rather
+    than on every trace of the batch programs."""
     dx = L_DOMAIN / n_cells
     x = (np.arange(n_cells) + 0.5) * dx
     return np.asarray(bathymetry(x, smoothed), np.float32)
 
 
-def _simulate(theta: jax.Array, n_cells: int, smoothed: bool):
-    """Traceable SWE core: returns (eta time series [n_steps, 2], dt)."""
-    dx = L_DOMAIN / n_cells
-    x = (np.arange(n_cells) + 0.5) * dx
-    b = jnp.asarray(_bathymetry_cached(n_cells, smoothed))
-    # still-water depth (clipped at dry land)
-    h0 = jnp.maximum(-b, 0.0)
-    x0 = theta[0] * 1e3
-    amp = theta[1]
-    eta0 = amp * jnp.exp(-(((jnp.asarray(x, jnp.float32) - x0) / 25e3) ** 2))
-    h = jnp.maximum(h0 + eta0 * (h0 > H_DRY), 0.0)
-    hu = jnp.zeros_like(h)
+def _transect_step(b: jax.Array, dt_dx: float):
+    """The 1-D time step on [cells, N] lanes of (h, hu) over the bathymetry
+    b [cells, 1]: desingularised velocity, hydrostatic reconstruction
+    against the face bathymetry max(b_L, b_R) (Audusse et al., well-balanced
+    with wetting and drying), Rusanov fluxes, the well-balanced momentum
+    correction, reflective walls, and the positivity / dry-cell limiter.
+    Every 1-D program runs this step; `repro.kernels.swe.ref.swe_step_ref`
+    is its oracle, bit-equal under `jit`."""
+    bL, bR = b[:-1], b[1:]
+    bstar = jnp.maximum(bL, bR)
 
-    c_max = float(np.sqrt(G * 4100.0))
-    dt = 0.3 * dx / c_max
-    n_steps = int(T_END / dt)
-    buoy_idx = jnp.asarray([int(bk * 1e3 / dx) for bk in BUOYS_KM])
-
-    def velocity(h, hu):
-        # desingularized velocity (avoids division blow-up at the shoreline)
+    def step(h, hu):
         h4 = h**4
-        return jnp.sqrt(2.0) * h * hu / jnp.sqrt(h4 + jnp.maximum(h, H_DRY) ** 4)
-
-    def step(carry, _):
-        h, hu = carry
-        u = velocity(h, hu)
-        # hydrostatic reconstruction (Audusse et al.): well-balanced w/ drying
-        bL, bR = b[:-1], b[1:]
-        bstar = jnp.maximum(bL, bR)
-        hsL = jnp.maximum(h[:-1] + bL - bstar, 0.0)
+        u = jnp.sqrt(2.0) * h * hu / jnp.sqrt(h4 + jnp.maximum(h, H_DRY) ** 4)
+        hsL = jnp.maximum(h[:-1] + bL - bstar, 0.0)  # [C-1, N]
         hsR = jnp.maximum(h[1:] + bR - bstar, 0.0)
         uL, uR = u[:-1], u[1:]
-        qL = jnp.stack([hsL, hsL * uL])
-        qR = jnp.stack([hsR, hsR * uR])
-        FL = jnp.stack([hsL * uL, hsL * uL * uL + 0.5 * G * hsL * hsL])
-        FR = jnp.stack([hsR * uR, hsR * uR * uR + 0.5 * G * hsR * hsR])
+        mL, mR = hsL * uL, hsR * uR  # interface mass fluxes
+        # _sqrt_safe == jnp.sqrt in the primal; only the adjoint differs
+        # (clamped at dry interfaces), keeping the step differentiable
         a = jnp.maximum(
-            jnp.abs(uL) + jnp.sqrt(G * hsL), jnp.abs(uR) + jnp.sqrt(G * hsR)
+            jnp.abs(uL) + _sqrt_safe(G * hsL), jnp.abs(uR) + _sqrt_safe(G * hsR)
         )
-        Fn = 0.5 * (FL + FR) - 0.5 * a * (qR - qL)  # [2, n-1]
-        # per-cell interface corrections (the well-balanced source)
-        corrL = 0.5 * G * (h[:-1] ** 2 - hsL**2)  # at right face of left cell
-        corrR = 0.5 * G * (h[1:] ** 2 - hsR**2)  # at left face of right cell
-        zero = jnp.zeros((1,))
-        # right-face flux seen by cell i / left-face flux seen by cell i;
-        # walls are reflective: zero mass flux, hydrostatic pressure G/2 h^2
-        F_right_h = jnp.concatenate([Fn[0], zero])
-        F_left_h = jnp.concatenate([zero, Fn[0]])
-        F_right_hu = jnp.concatenate([Fn[1] + corrL, 0.5 * G * h[-1:] ** 2])
-        F_left_hu = jnp.concatenate([0.5 * G * h[:1] ** 2, Fn[1] + corrR])
-        h_new = h - dt / dx * (F_right_h - F_left_h)
-        hu_new = hu - dt / dx * (F_right_hu - F_left_hu)
-        h_new = jnp.maximum(h_new, 0.0)
-        hu_new = jnp.where(h_new > H_DRY, hu_new, 0.0)
-        eta_b = h_new[buoy_idx] - jnp.maximum(-b, 0.0)[buoy_idx]
-        return (h_new, hu_new), eta_b
+        Fh = 0.5 * (mL + mR) - 0.5 * a * (hsR - hsL)
+        Fq = 0.5 * ((mL * uL + 0.5 * G * hsL * hsL) + (mR * uR + 0.5 * G * hsR * hsR)) \
+            - 0.5 * a * (mR - mL)
+        # momentum flux + well-balanced interface correction, as seen from
+        # the left cell (A) and from the right cell (B)
+        A = Fq + 0.5 * G * (h[:-1] ** 2 - hsL**2)
+        B = Fq + 0.5 * G * (h[1:] ** 2 - hsR**2)
+        # flux divergence per cell; reflective walls (zero mass flux,
+        # hydrostatic pressure G/2 h^2)
+        div_h = jnp.concatenate([Fh[:1], Fh[1:] - Fh[:-1], -Fh[-1:]], 0)
+        pL = 0.5 * G * h[:1] ** 2
+        pR = 0.5 * G * h[-1:] ** 2
+        div_hu = jnp.concatenate([A[:1] - pL, A[1:] - B[:-1], pR - B[-1:]], 0)
+        h_new = jnp.maximum(h - dt_dx * div_h, 0.0)
+        hu_new = jnp.where(h_new > H_DRY, hu - dt_dx * div_hu, 0.0)
+        return h_new, hu_new
 
-    (_, _), etas = jax.lax.scan(step, (h, hu), None, length=n_steps)
-    return etas, dt
-
-
-# jitted per-point view (the seed's `_solve` API): [n_steps, 2] time series
-_solve = jax.jit(_simulate, static_argnames=("n_cells", "smoothed"))
+    return step
 
 
 @partial(jax.jit, static_argnames=("n_cells", "smoothed", "swe_impl"))
 def _solve_batch(
     thetas: jax.Array, n_cells: int, smoothed: bool, swe_impl: str = "scan"
 ) -> jax.Array:
-    """[N, 2] -> [N, 4]: ONE jitted program solving all N sources in lockstep.
+    """[N, 2] -> [N, 4]: ONE jitted program solving all N sources in lockstep,
+    a `lax.scan` of `_transect_step` over the time steps.
 
-    This is a hand-batched rework of `_simulate` tuned for throughput rather
-    than per-point latency:
       * state is laid out [n_cells, N] (batch LAST): every stencil slice
         (`h[:-1]`, `h[1:]`) and boundary concatenate is then a contiguous
-        memory op instead of a strided copy per lane — on CPU this alone is
-        worth >3x over the naive vmap layout;
+        memory op instead of a strided copy per lane;
       * the arrival-time / max-height observable reduction runs INSIDE the
-        scan carry, so only [N, 4] ever leaves the device — the per-point
-        path materializes the full [n_steps, 2] series on the host;
+        scan carry, so only [N, 4] ever leaves the device;
       * buoys are read with static row slices instead of a gather, and the
-        hydrostatic-reconstruction bathymetry offsets are precomputed.
-    Same Rusanov/hydrostatic-reconstruction arithmetic, so results match the
-    per-point path up to float32 reassociation."""
+        face bathymetry is computed once, outside the loop.
+
+    `swe_impl` is ignored: it is kept, static, only so that wrappers in
+    `tests/bench/faults.py` that pass it through still call this program."""
     dx = L_DOMAIN / n_cells
     x = jnp.asarray((np.arange(n_cells) + 0.5) * dx, jnp.float32)[:, None]
     b = jnp.asarray(_bathymetry_cached(n_cells, smoothed))[:, None]  # [C, 1]
     h0s = jnp.maximum(-b, 0.0)
-    bL, bR = b[:-1], b[1:]
-    bstar = jnp.maximum(bL, bR)
+    dt = CFL * dx / C_MAX
+    advance = _transect_step(b, dt / dx)
 
-    c_max = float(np.sqrt(G * 4100.0))
-    dt = 0.3 * dx / c_max
     n_steps = int(T_END / dt)
     buoy_rows = tuple(int(bk * 1e3 / dx) for bk in BUOYS_KM)
     h0_buoy = jnp.stack([h0s[r, 0] for r in buoy_rows])  # [2]
@@ -225,42 +188,7 @@ def _solve_batch(
 
     def step(carry, i):
         h, hu, mx, arr = carry
-        if swe_impl != "scan":
-            # fused kernels.swe stencil: flux + limiter + update in one
-            # kernel pass per step (forward waves only — see `_swe_impl`)
-            h_new, hu_new = swe_step(
-                h, hu, b, dt_dx=float(dt / dx), g=G, h_dry=H_DRY, impl=swe_impl
-            )
-        else:
-            h4 = h**4
-            u = jnp.sqrt(2.0) * h * hu / jnp.sqrt(h4 + jnp.maximum(h, H_DRY) ** 4)
-            # identical operation ORDER to `_simulate`'s step (not just
-            # identical math): float32 reassociation would otherwise drift
-            # over the ~1e4 steps of the fine level
-            hsL = jnp.maximum(h[:-1] + bL - bstar, 0.0)  # [C-1, N]
-            hsR = jnp.maximum(h[1:] + bR - bstar, 0.0)
-            uL, uR = u[:-1], u[1:]
-            mL, mR = hsL * uL, hsR * uR  # interface mass fluxes
-            # _sqrt_safe == jnp.sqrt in the primal; only the adjoint differs
-            # (clamped at dry interfaces), keeping this path differentiable
-            a = jnp.maximum(
-                jnp.abs(uL) + _sqrt_safe(G * hsL), jnp.abs(uR) + _sqrt_safe(G * hsR)
-            )
-            Fh = 0.5 * (mL + mR) - 0.5 * a * (hsR - hsL)
-            Fq = 0.5 * ((mL * uL + 0.5 * G * hsL * hsL) + (mR * uR + 0.5 * G * hsR * hsR)) \
-                - 0.5 * a * (mR - mL)
-            # momentum flux + well-balanced interface correction, as seen
-            # from the left cell (A) and from the right cell (B)
-            A = Fq + 0.5 * G * (h[:-1] ** 2 - hsL**2)
-            B = Fq + 0.5 * G * (h[1:] ** 2 - hsR**2)
-            # flux divergence per cell; reflective walls (zero mass flux,
-            # hydrostatic pressure G/2 h^2)
-            div_h = jnp.concatenate([Fh[:1], Fh[1:] - Fh[:-1], -Fh[-1:]], 0)
-            pL = 0.5 * G * h[:1] ** 2
-            pR = 0.5 * G * h[-1:] ** 2
-            div_hu = jnp.concatenate([A[:1] - pL, A[1:] - B[:-1], pR - B[-1:]], 0)
-            h_new = jnp.maximum(h - dt / dx * div_h, 0.0)
-            hu_new = jnp.where(h_new > H_DRY, hu - dt / dx * div_hu, 0.0)
+        h_new, hu_new = advance(h, hu)
         eta_b = jnp.stack([h_new[r] for r in buoy_rows], 0) - h0_buoy[:, None]  # [2, N]
         mx = jnp.maximum(mx, eta_b)
         arr = jnp.where((jnp.abs(eta_b) > ARRIVAL_THRESH) & (arr < 0), i, arr)
@@ -488,8 +416,8 @@ def _sea_scan(h, grid: Grid, buoys: tuple):
     return mx, arr
 
 
-# Chunked dispatch for `evaluate_batch`: concurrent jitted solves on
-# power-of-2-wide chunks. Two effects stack: chunks stay cache-resident
+# Chunked dispatch for every batched op (`TsunamiModel._wave`): concurrent
+# jitted solves on power-of-2-wide chunks. Two effects stack: chunks stay cache-resident
 # ([C, <=64] working sets), and PJRT CPU executes concurrent computations on
 # separate cores — XLA does not parallelize inside a `while` loop body, so
 # thread-level chunking is how a CPU batch actually uses all cores.
@@ -520,19 +448,6 @@ def chunk_plan(N: int, chunk_max: int = _CHUNK_MAX) -> list:
             for lo in range(0, N, chunk)]
 
 
-def observables(theta, n_cells: int, smoothed: bool) -> np.ndarray:
-    """[arrival_1 (min), height_1 (m), arrival_2, height_2]."""
-    etas, dt = _solve(jnp.asarray(theta, jnp.float32), n_cells, smoothed)
-    etas = np.asarray(etas)
-    out = []
-    for bi in range(len(BUOYS_KM)):
-        sig = np.abs(etas[:, bi])
-        above = sig > ARRIVAL_THRESH
-        arrival = (np.argmax(above) * float(dt) / 60.0) if above.any() else T_END / 60.0
-        out.extend([arrival, float(etas[:, bi].max())])
-    return np.asarray(out)
-
-
 class TsunamiModel(Model):
     """UM-Bridge model: theta -> 4 observables, at the levels of `grids`.
     config: {"level": 0 (coarse/smoothed, default) | 1 (fully resolved)}.
@@ -548,11 +463,12 @@ class TsunamiModel(Model):
     #: the 2-D sea: the paper's smoothed level (240^2 cells x 3 fields =
     #: 172,800 dof) and a finer level with the ridges (480^2 x 3 = 691,200)
     GRIDS_2D = {0: Grid(240, 240, smoothed=True), 1: Grid(480, 480, smoothed=False)}
-    # chunks + pads internally (see evaluate_batch) — dispatcher-level
+    # chunks + pads internally (see `_wave`) — dispatcher-level
     # pow2 padding would only add wasted solves on top
     batch_bucket = False
-    #: gradient-wave chunk width: reverse-mode stores the scan carry per
-    #: step, so gradient lanes cost ~3x the memory of evaluate lanes
+    #: chunk width of every derivative op's wave (evaluate: `_CHUNK_MAX`):
+    #: reverse-mode stores the scan carry per step, so derivative lanes cost
+    #: ~3x the memory of evaluate lanes
     GRAD_CHUNK_MAX = 16
 
     #: cap on cached fused specializations (one per distinct sens_fn object)
@@ -591,39 +507,31 @@ class TsunamiModel(Model):
             apply_hessian=True, apply_hessian_batch=True,
         )
 
-    def _transect(self, config) -> tuple[int, bool]:
-        """(n_cells, smoothed) of a 1-D level: the derivative and per-point
-        paths exist for the transect only."""
-        if self.two_d:
+    def _grid(self, op: str, config) -> tuple[int, Grid]:
+        """(level, grid) that `config` names; the 2-D sea serves evaluate
+        only."""
+        level = int((config or {}).get("level", 0))
+        grid = self.grids[level]
+        if grid.ny and op != "evaluate":
             raise NotImplementedError(
                 "the 2-D sea serves evaluate and evaluate_batch only")
-        grid = self.grids[int((config or {}).get("level", 0))]
-        return grid.nx, grid.smoothed
+        return level, grid
 
     def __call__(self, parameters, config=None):
-        if self.two_d:
-            return [list(map(float, self.evaluate_batch([parameters[0]], config)[0]))]
-        level = int((config or {}).get("level", 0))
-        theta = np.asarray(parameters[0], float)
-        with self._lock:
-            self.stats[level] += 1
-        n_cells, smoothed = self._transect(config)
-        obs = observables(theta, n_cells, smoothed)
-        return [list(map(float, obs))]
+        return [list(map(float, self.evaluate_batch([parameters[0]], config)[0]))]
 
     def padded_lanes(self) -> int:
         """Lanes added by chunk padding, over every wave so far."""
         with self._lock:
             return sum(c["padded"] for c in self.chunk_stats.values())
 
-    def _map_chunks(self, level: int, N: int, chunk_max: int, solve, kernel: str = "xla") -> list:
+    def _map_chunks(self, level: int, N: int, chunk_max: int, solve, kernel: str) -> list:
         """[solve(lo, hi, bucket), ...] over the chunks of a wave of N lanes:
         lanes [lo, hi) padded to `bucket`, a power of two, so the jit cache
         holds a handful of shapes per level. Several chunks run CONCURRENTLY
-        on the host executor (see `_solve_batch` for why that beats one
-        monolithic dispatch on CPU), each in a `model.chunk` span under the
-        caller's span that names the path `solve` takes (`kernel`: "pallas"
-        or "xla")."""
+        on the host executor (PJRT CPU runs concurrent programs on separate
+        cores), each in a `model.chunk` span under the caller's span that
+        names the path `solve` takes (`kernel`: "pallas" or "xla")."""
         plan = chunk_plan(N, chunk_max)
         lanes = sum(bucket for _, _, bucket in plan)
         with self._lock:
@@ -646,27 +554,35 @@ class TsunamiModel(Model):
             return [run(plan[0])]
         return list(_chunk_executor().map(run, plan))
 
-    def evaluate_batch(self, thetas, config=None) -> np.ndarray:
-        """[N, 2] -> [N, 4] through the lockstep batch solver of the level's
-        grid (1-D or 2-D), in chunks of at most 64 lanes (`_map_chunks`)."""
-        level = int((config or {}).get("level", 0))
-        grid = self.grids[level]
-        thetas = np.atleast_2d(np.asarray(thetas, np.float32))
-        if grid.ny:
-            kernel = swe2d.sea_path(grid.ny, grid.nx)
-        else:
-            kernel = "xla" if _swe_impl() in ("scan", "ref") else "pallas"
+    def _wave(self, op: str, config, operands, program):
+        """One batched op over a wave: the operands' rows [N, .] in chunks
+        (`_map_chunks`), each padded to its bucket and run as
+        `program(grid, *chunk)`, waited for, trimmed and joined; a program
+        that returns a tuple gives a tuple of joined outputs."""
+        level, grid = self._grid(op, config)
+        operands = [np.atleast_2d(np.asarray(x, np.float32)) for x in operands]
+        chunk_max = _CHUNK_MAX if op == "evaluate" else self.GRAD_CHUNK_MAX
+        kernel = swe2d.sea_path(grid.ny, grid.nx) if grid.ny else "xla"
 
         def solve(lo, hi, bucket):
-            padded = jnp.asarray(pad_to_bucket(thetas[lo:hi], bucket)[0])
-            if grid.ny:
-                out = _solve_batch_2d(padded, grid)
-            else:
-                out = _solve_batch(padded, grid.nx, grid.smoothed, _swe_impl())
-            return np.asarray(tracing.wait(out), float)[: hi - lo]
+            out = program(grid, *(jnp.asarray(pad_to_bucket(x[lo:hi], bucket)[0])
+                                  for x in operands))
+            outs = tracing.wait(out if isinstance(out, tuple) else (out,))
+            return [np.asarray(o, float)[: hi - lo] for o in outs]
 
-        return np.concatenate(
-            self._map_chunks(level, len(thetas), _CHUNK_MAX, solve, kernel), axis=0)
+        parts = self._map_chunks(level, len(operands[0]), chunk_max, solve, kernel)
+        joined = tuple(np.concatenate(col, axis=0) for col in zip(*parts))
+        return joined if len(joined) > 1 else joined[0]
+
+    def evaluate_batch(self, thetas, config=None) -> np.ndarray:
+        """[N, 2] -> [N, 4] through the lockstep batch solver of the level's
+        grid (1-D or 2-D), in chunks of at most 64 lanes."""
+        def program(grid, th):
+            if grid.ny:
+                return _solve_batch_2d(th, grid)
+            return _solve_batch(th, grid.nx, grid.smoothed)
+
+        return self._wave("evaluate", config, [thetas], program)
 
     # -- batched derivative surface -----------------------------------------
     def gradient(self, out_wrt, in_wrt, parameters, sens, config=None):
@@ -676,22 +592,9 @@ class TsunamiModel(Model):
         return self.gradient_batch(theta[None, :], sens4[None, :], config)[0].tolist()
 
     def gradient_batch(self, thetas, senss, config=None) -> np.ndarray:
-        """[N, 2] x [N, 4] -> [N, 2]: lockstep reverse-mode waves, chunked
-        narrower than evaluate waves (reverse stores the scan carry) and
-        solved concurrently on the host executor like `evaluate_batch`."""
-        level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self._transect(config)
-        thetas = np.atleast_2d(np.asarray(thetas, np.float32))
-        senss = np.atleast_2d(np.asarray(senss, np.float32))
-
-        def solve(lo, hi, bucket):
-            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
-            ps, _ = pad_to_bucket(senss[lo:hi], bucket)
-            _, g = _vjp_batch(jnp.asarray(pt), jnp.asarray(ps), n_cells, smoothed)
-            return np.asarray(tracing.wait(g), float)[: hi - lo]
-
-        return np.concatenate(
-            self._map_chunks(level, len(thetas), self.GRAD_CHUNK_MAX, solve), axis=0)
+        """[N, 2] x [N, 4] -> [N, 2]: lockstep reverse-mode waves."""
+        return self._wave("gradient", config, [thetas, senss],
+                          lambda grid, th, s: _vjp_batch(th, s, grid.nx, grid.smoothed)[1])
 
     def apply_jacobian(self, out_wrt, in_wrt, parameters, vec, config=None):
         theta = np.asarray(parameters[in_wrt], float)
@@ -701,19 +604,8 @@ class TsunamiModel(Model):
 
     def apply_jacobian_batch(self, thetas, vecs, config=None) -> np.ndarray:
         """[N, 2] x [N, 2] -> [N, 4]: lockstep forward-mode (JVP) waves."""
-        level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self._transect(config)
-        thetas = np.atleast_2d(np.asarray(thetas, np.float32))
-        vecs = np.atleast_2d(np.asarray(vecs, np.float32))
-
-        def solve(lo, hi, bucket):
-            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
-            pv, _ = pad_to_bucket(vecs[lo:hi], bucket)
-            out = _jvp_batch(jnp.asarray(pt), jnp.asarray(pv), n_cells, smoothed)
-            return np.asarray(tracing.wait(out), float)[: hi - lo]
-
-        return np.concatenate(
-            self._map_chunks(level, len(thetas), self.GRAD_CHUNK_MAX, solve), axis=0)
+        return self._wave("apply_jacobian", config, [thetas, vecs],
+                          lambda grid, th, v: _jvp_batch(th, v, grid.nx, grid.smoothed))
 
     def apply_hessian(self, out_wrt, in_wrt1, in_wrt2, parameters, sens, vec, config=None):
         theta = np.asarray(parameters[in_wrt1], float)
@@ -725,26 +617,9 @@ class TsunamiModel(Model):
 
     def apply_hessian_batch(self, thetas, senss, vecs, config=None) -> np.ndarray:
         """[N, 2] x [N, 4] x [N, 2] -> [N, 2]: lockstep HVP waves
-        (reverse-over-forward through the batch solver). Chunked like
-        gradient waves — the reverse sweep dominates the footprint, the
-        forward-mode tangents ride along at carry cost."""
-        level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self._transect(config)
-        thetas = np.atleast_2d(np.asarray(thetas, np.float32))
-        senss = np.atleast_2d(np.asarray(senss, np.float32))
-        vecs = np.atleast_2d(np.asarray(vecs, np.float32))
-
-        def solve(lo, hi, bucket):
-            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
-            ps, _ = pad_to_bucket(senss[lo:hi], bucket)
-            pv, _ = pad_to_bucket(vecs[lo:hi], bucket)
-            out = _hvp_batch(
-                jnp.asarray(pt), jnp.asarray(ps), jnp.asarray(pv), n_cells, smoothed
-            )
-            return np.asarray(tracing.wait(out), float)[: hi - lo]
-
-        return np.concatenate(
-            self._map_chunks(level, len(thetas), self.GRAD_CHUNK_MAX, solve), axis=0)
+        (reverse-over-forward through the batch solver)."""
+        return self._wave("apply_hessian", config, [thetas, senss, vecs],
+                          lambda grid, th, s, v: _hvp_batch(th, s, v, grid.nx, grid.smoothed))
 
     def value_and_gradient_batch(self, thetas, sens_fn, config=None):
         """Fused (ys, grads) in ONE jitted dispatch per chunk when `sens_fn`
@@ -753,18 +628,15 @@ class TsunamiModel(Model):
         Traceability is probed abstractly up front (`sens_fn_traceable`), so
         a transient dispatch error never silently downgrades the fused path;
         the per-sens_fn program cache is LRU-bounded."""
-        level = int((config or {}).get("level", 0))
-        n_cells, smoothed = self._transect(config)
-        thetas = np.atleast_2d(np.asarray(thetas, np.float32))
-        N = len(thetas)
+        level, grid = self._grid("value_and_gradient", config)
         if not sens_fn_traceable(sens_fn, 4, jnp.float32):
             return super().value_and_gradient_batch(thetas, sens_fn, config)
         key = (level, sens_fn)
         with self._lock:
             if key not in self._vgrad_cache:
-                @partial(jax.jit)
+                @jax.jit
                 def fused(th):
-                    y, vjp = jax.vjp(lambda t: _solve_batch(t, n_cells, smoothed), th)
+                    y, vjp = jax.vjp(lambda t: _solve_batch(t, grid.nx, grid.smoothed), th)
                     senss = jax.vmap(sens_fn)(y)
                     return y, vjp(jnp.asarray(senss, y.dtype))[0]
                 self._vgrad_cache[key] = fused
@@ -772,31 +644,5 @@ class TsunamiModel(Model):
                     self._vgrad_cache.popitem(last=False)
             self._vgrad_cache.move_to_end(key)
             fused_fn = self._vgrad_cache[key]
-
-        def solve(lo, hi, bucket):
-            pt, _ = pad_to_bucket(thetas[lo:hi], bucket)
-            y, g = tracing.wait(fused_fn(jnp.asarray(pt)))
-            return np.asarray(y, float)[: hi - lo], np.asarray(g, float)[: hi - lo]
-
-        parts = self._map_chunks(level, N, self.GRAD_CHUNK_MAX, solve)
-        return (np.concatenate([p[0] for p in parts], axis=0),
-                np.concatenate([p[1] for p in parts], axis=0))
-
-
-def make_logposts(model: TsunamiModel, data: np.ndarray, noise_sd, prior_bounds):
-    """Per-level log-posteriors for MLDA. Gaussian likelihood on the 4
-    observables; uniform prior box on (x0, A)."""
-    noise_sd = np.asarray(noise_sd, float)
-    (x_lo, x_hi), (a_lo, a_hi) = prior_bounds
-
-    def make(level):
-        def logpost(theta):
-            x0, A = float(theta[0]), float(theta[1])
-            if not (x_lo <= x0 <= x_hi and a_lo <= A <= a_hi):
-                return -np.inf
-            obs = np.asarray(model([list(theta)], {"level": level})[0])
-            return float(-0.5 * np.sum(((obs - data) / noise_sd) ** 2))
-
-        return logpost
-
-    return make
+        return self._wave("value_and_gradient", config, [thetas],
+                          lambda grid, th: fused_fn(th))

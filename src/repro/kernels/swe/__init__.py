@@ -1,5 +1,8 @@
-from repro.kernels.swe.ops import swe_step
-from repro.kernels.swe.ref import swe2d_simulate_ref, swe2d_step_ref, swe_step_ref
-from repro.kernels.swe.swe import swe_step_kernel
+from repro.kernels.swe.ref import (
+    swe2d_simulate_ref,
+    swe2d_step_ref,
+    swe_simulate_ref,
+    swe_step_ref,
+)
 
-__all__ = ["swe_step", "swe_step_ref", "swe_step_kernel", "swe2d_step_ref", "swe2d_simulate_ref"]
+__all__ = ["swe_step_ref", "swe_simulate_ref", "swe2d_step_ref", "swe2d_simulate_ref"]

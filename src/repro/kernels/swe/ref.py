@@ -1,14 +1,17 @@
-"""Oracle for the fused SWE stencil kernel.
+"""Plain references of the tsunami model's shallow-water steps.
 
-One Rusanov / hydrostatic-reconstruction finite-volume step over a
-``[cells, batch]`` state block — the exact arithmetic (and the exact
-OPERATION ORDER) of the scan body in `repro.apps.tsunami._solve_batch`:
-hydrostatic reconstruction against the interface bathymetry (Audusse et
-al., well-balanced with wetting & drying), Rusanov flux, well-balanced
-momentum corrections, reflective walls, positivity/dry-cell limiter.
-`apps.tsunami` keeps this math inline as its default scan body; the Pallas
-kernel (`repro.kernels.swe.swe`) must match this reference bit-for-bit in
-interpret mode, which is what `tests/test_kernels.py` gates.
+`swe_step_ref` is one Rusanov / hydrostatic-reconstruction finite-volume
+step over a ``[cells, batch]`` state block, the oracle of the 1-D step every
+program of `repro.apps.tsunami` runs (`_transect_step`): the exact
+arithmetic and OPERATION ORDER, with `jnp.sqrt` where the program's step has
+its clamped-adjoint `_sqrt_safe` (equal in the primal). Hydrostatic
+reconstruction against the interface bathymetry (Audusse et al.,
+well-balanced with wetting & drying), Rusanov flux, well-balanced momentum
+corrections, reflective walls, positivity/dry-cell limiter.
+`tests/test_kernels.py` holds the two bit-equal under `jit`.
+`swe_simulate_ref` runs it for one source at a time, every step's buoy
+elevation kept: the per-source side `tests/test_batch_native.py` compares
+the batch program against.
 
 `swe2d_step_ref` / `swe2d_simulate_ref` are the plain reference of the 2-D
 sea (`repro.apps.tsunami._solve_batch_2d`): one source at a time, written
@@ -63,6 +66,32 @@ def swe_step_ref(
     h_new = jnp.maximum(h - dt_dx * div_h, 0.0)
     hu_new = jnp.where(h_new > h_dry, hu - dt_dx * div_hu, 0.0)
     return h_new, hu_new
+
+
+def swe_simulate_ref(
+    h: jax.Array,  # [C] initial depth of one source
+    b: jax.Array,  # [C]
+    dt_dx: float,
+    n_steps: int,
+    rows: tuple,  # cells of the buoys
+    *,
+    g: float = G,
+    h_dry: float = H_DRY,
+) -> jax.Array:
+    """[n_steps, len(rows)] surface elevation at the buoys after each step,
+    starting at rest from depth `h`: `swe_step_ref` in a plain loop over
+    time steps, one source at a time, every step's elevation kept (the
+    arrival and the maximum are left to the caller)."""
+    h_still = jnp.maximum(-b, 0.0)
+    b = b[:, None]
+
+    def step(state, _):
+        state = swe_step_ref(*state, b, dt_dx, g=g, h_dry=h_dry)
+        return state, jnp.stack([state[0][r, 0] - h_still[r] for r in rows])
+
+    h = h[:, None]
+    _, etas = jax.lax.scan(step, (h, jnp.zeros_like(h)), None, length=n_steps)
+    return etas
 
 
 def swe2d_step_ref(

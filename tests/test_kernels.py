@@ -12,7 +12,7 @@ from repro.kernels.rmsnorm.ref import rmsnorm_ref
 from repro.kernels.ssd.ops import ssd as ssd_op
 from repro.kernels.ssd.ref import ssd_ref
 from repro.kernels.ssd.ssd import ssd_kernel
-from repro.kernels.swe.ops import swe_step
+from repro.apps.tsunami import _transect_step
 from repro.kernels.swe.ref import swe_step_ref
 
 FLASH_CASES = [
@@ -99,7 +99,7 @@ def test_rmsnorm_vs_ref(case):
     assert err < tol, err
 
 
-# -- SWE Rusanov stencil ------------------------------------------------------
+# -- SWE Rusanov step ---------------------------------------------------------
 
 def _swe_state(kind, C=48, N=32):
     """[cells, batch] shallow-water states exercising the limiter branches."""
@@ -123,10 +123,14 @@ def _swe_state(kind, C=48, N=32):
     return jnp.asarray(h), jnp.asarray(hu), jnp.asarray(b)
 
 
+# the 1-D step every tsunami program runs, as a program traces it
+_program_step = jax.jit(lambda h, hu, b: _transect_step(b, 0.02)(h, hu))
+
+
 @pytest.mark.parametrize("kind", ["lake_at_rest", "dam_break", "dry_bed", "moving"])
 def test_swe_step_vs_ref(kind):
     h, hu, b = _swe_state(kind)
-    out_h, out_hu = swe_step(h, hu, b, dt_dx=0.02, impl="interpret")
+    out_h, out_hu = _program_step(h, hu, b)
     ref_h, ref_hu = swe_step_ref(h, hu, b, 0.02)
     np.testing.assert_allclose(np.asarray(out_h), np.asarray(ref_h),
                                rtol=1e-4, atol=1e-6)
@@ -135,34 +139,36 @@ def test_swe_step_vs_ref(kind):
 
 
 def test_swe_step_bitwise_vs_jitted_ref():
-    # the only kernel/ref delta is XLA's FMA contraction inside jit; the
-    # jitted ref compiles to the same contractions, so this is bit-exact
-    h, hu, b = _swe_state("dam_break")
+    # eager and jitted differ by XLA's FMA contraction inside jit; the
+    # jitted ref compiles to the same contractions, and `_sqrt_safe` is
+    # `jnp.sqrt` in the primal, so this is bit-exact
     jref = jax.jit(lambda a, q, bb: swe_step_ref(a, q, bb, 0.02))
-    ref_h, ref_hu = jref(h, hu, b)
-    out_h, out_hu = swe_step(h, hu, b, dt_dx=0.02, impl="interpret")
-    assert np.array_equal(np.asarray(out_h), np.asarray(ref_h))
-    assert np.array_equal(np.asarray(out_hu), np.asarray(ref_hu))
+    for kind in ("dam_break", "dry_bed"):
+        h, hu, b = _swe_state(kind)
+        ref_h, ref_hu = jref(h, hu, b)
+        out_h, out_hu = _program_step(h, hu, b)
+        assert np.array_equal(np.asarray(out_h), np.asarray(ref_h))
+        assert np.array_equal(np.asarray(out_hu), np.asarray(ref_hu))
 
 
 def test_swe_step_well_balanced_and_dry_invariants():
     # lake at rest stays at rest (well-balanced hydrostatic reconstruction)
     h, hu, b = _swe_state("lake_at_rest")
-    out_h, out_hu = swe_step(h, hu, b, dt_dx=0.02, impl="interpret")
+    out_h, out_hu = _program_step(h, hu, b)
     np.testing.assert_allclose(np.asarray(out_h), np.asarray(h), atol=1e-6)
     np.testing.assert_allclose(np.asarray(out_hu), 0.0, atol=1e-6)
     # dry cells: depth stays non-negative, momentum zeroed below threshold
     h, hu, b = _swe_state("dry_bed")
-    out_h, out_hu = swe_step(h, hu, b, dt_dx=0.02, impl="interpret")
+    out_h, out_hu = _program_step(h, hu, b)
     oh, ohu = np.asarray(out_h), np.asarray(out_hu)
     assert (oh >= 0.0).all()
     assert (ohu[oh <= 0.05] == 0.0).all()
 
 
 def test_swe_step_odd_batch_tile_clamp():
-    # N=24 forces the pow2 tile clamp (blk 128 -> 8); grid still covers all
+    # N=24: a batch that is not a power of two, as a trimmed wave can be
     h, hu, b = _swe_state("moving", N=24)
-    out_h, out_hu = swe_step(h, hu, b, dt_dx=0.02, impl="interpret")
+    out_h, out_hu = _program_step(h, hu, b)
     ref_h, ref_hu = swe_step_ref(h, hu, b, 0.02)
     np.testing.assert_allclose(np.asarray(out_h), np.asarray(ref_h),
                                rtol=1e-4, atol=1e-6)
