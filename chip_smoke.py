@@ -13,9 +13,10 @@ Phases, each checked against a reference, at the model's full width (level 0:
   reference `swe_simulate_ref`, run on the host CPU and reduced there.
 * campaign: `ensemble_mlda` through `EvaluationFabric(ModelBackend(...))`,
   and one fused device-resident RWM block over the level-0 batch solver.
-* kernel: the 2-D sea's time-loop Pallas kernel (`kernels/swe2d`) against
-  its jnp scan on a 16-lane chunk of the smoothed level; its lowering must
-  hold `tpu_custom_call`, so it is not interpret mode.
+* kernel: the time-loop Pallas kernels against their jnp scans on a
+  16-lane chunk: the 1-D transect's (`kernels/swe1d`) at both levels and the
+  2-D sea's (`kernels/swe2d`) at the smoothed level; each lowering must hold
+  `tpu_custom_call`, so it is not interpret mode.
 * sharded (`--chips 4`): a `ModelPool` wave and a fused chain block over a
   4x1 `data` mesh must equal their one-device results.
 
@@ -235,22 +236,13 @@ def campaign_phase(rng, n_chains: int = 64, n_fine: int = 3, n_sub: int = 4,
     return info
 
 
-def kernel_phase(rng, lanes: int = 16) -> dict:
-    """The 2-D sea's Pallas time-loop kernel against its jnp scan on one
-    chunk of the smoothed level, as a 128-point wave cuts it on the chip's
-    host."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.apps.tsunami import TsunamiModel, _sea_observables
-
-    grid = TsunamiModel.GRIDS_2D[0]
-    thetas = jnp.asarray(np.stack([rng.uniform(40.0, 140.0, lanes),
-                                   rng.uniform(120.0, 280.0, lanes)], axis=1), jnp.float32)
-    runs = {path: jax.jit(partial(_sea_observables, grid=grid, path=path))
-            for path in ("pallas", "xla")}
+def _kernel_vs_scan(name: str, runs: dict, thetas, level: int) -> dict:
+    """Run a Pallas time-loop kernel and its jnp scan (`runs`: path ->
+    jitted program) on the same chunk: the kernel's lowering must hold a
+    `tpu_custom_call`, its rows must match the scan's to the level's
+    tolerances; returns the first and second call times and the gap."""
     if "tpu_custom_call" not in runs["pallas"].lower(thetas).as_text():
-        raise AssertionError("the 2-D Pallas lowering holds no tpu_custom_call")
+        raise AssertionError(f"the {name} Pallas lowering holds no tpu_custom_call")
     info, outs = {}, {}
     for path, run in runs.items():
         t0 = time.perf_counter()
@@ -258,11 +250,36 @@ def kernel_phase(rng, lanes: int = 16) -> dict:
         first = time.perf_counter() - t0
         t0 = time.perf_counter()
         np.asarray(run(thetas))
-        info[f"{path}_2d_l0_x{lanes}_s"] = {
+        info[f"{path}_{name}_x{len(thetas)}_s"] = {
             "first_call": first, "second_call": time.perf_counter() - t0,
         }
-    _check_finite_nonzero("pallas 2-D level 0", outs["pallas"], (lanes, 4))
-    info["pallas_vs_xla_2d_l0"] = _compare(outs["pallas"], outs["xla"], 0)
+    _check_finite_nonzero(f"pallas {name}", outs["pallas"], (len(thetas), 4))
+    info[f"pallas_vs_xla_{name}"] = _compare(outs["pallas"], outs["xla"], level)
+    return info
+
+
+def kernel_phase(rng, lanes: int = 16) -> dict:
+    """The Pallas time-loop kernels against their jnp scans on one chunk, as
+    a 128-point wave cuts it on the chip's host: the 1-D transect's at both
+    levels, and the 2-D sea's at the smoothed level."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.apps.tsunami import TsunamiModel, _sea_observables, _transect_observables
+
+    info = {}
+    thetas = jnp.asarray(_points(rng, lanes), jnp.float32)
+    for level, grid in TsunamiModel().grids.items():
+        runs = {path: jax.jit(partial(_transect_observables, n_cells=grid.nx,
+                                      smoothed=grid.smoothed, path=path))
+                for path in ("pallas", "xla")}
+        info.update(_kernel_vs_scan(f"1d_l{level}", runs, thetas, level))
+    grid = TsunamiModel.GRIDS_2D[0]
+    thetas = jnp.asarray(np.stack([rng.uniform(40.0, 140.0, lanes),
+                                   rng.uniform(120.0, 280.0, lanes)], axis=1), jnp.float32)
+    runs = {path: jax.jit(partial(_sea_observables, grid=grid, path=path))
+            for path in ("pallas", "xla")}
+    info.update(_kernel_vs_scan("2d_l0", runs, thetas, 0))
     return info
 
 

@@ -43,7 +43,7 @@ from repro.core.interface import (
     pad_to_bucket,
     sens_fn_traceable,
 )
-from repro.kernels import swe2d
+from repro.kernels import swe1d, swe2d
 from repro.kernels.swe2d.swe2d import G, H_DRY, face_flux, update, velocities
 
 
@@ -113,42 +113,55 @@ def _transect_step(b: jax.Array, dt_dx: float):
     b [cells, 1]: desingularised velocity, hydrostatic reconstruction
     against the face bathymetry max(b_L, b_R) (Audusse et al., well-balanced
     with wetting and drying), Rusanov fluxes, the well-balanced momentum
-    correction, reflective walls, and the positivity / dry-cell limiter.
-    Every 1-D program runs this step; `repro.kernels.swe.ref.swe_step_ref`
-    is its oracle, bit-equal under `jit`."""
+    correction, reflective walls, and the positivity / dry-cell limiter,
+    from the functions `repro.kernels.swe1d` shares with its kernel.
+    `repro.kernels.swe.ref.swe_step_ref` is its oracle, bit-equal under
+    `jit`."""
     bL, bR = b[:-1], b[1:]
     bstar = jnp.maximum(bL, bR)
 
     def step(h, hu):
-        h4 = h**4
-        u = jnp.sqrt(2.0) * h * hu / jnp.sqrt(h4 + jnp.maximum(h, H_DRY) ** 4)
-        hsL = jnp.maximum(h[:-1] + bL - bstar, 0.0)  # [C-1, N]
-        hsR = jnp.maximum(h[1:] + bR - bstar, 0.0)
-        uL, uR = u[:-1], u[1:]
-        mL, mR = hsL * uL, hsR * uR  # interface mass fluxes
+        u = swe1d.velocity(h, hu)
         # _sqrt_safe == jnp.sqrt in the primal; only the adjoint differs
         # (clamped at dry interfaces), keeping the step differentiable
-        a = jnp.maximum(
-            jnp.abs(uL) + _sqrt_safe(G * hsL), jnp.abs(uR) + _sqrt_safe(G * hsR)
-        )
-        Fh = 0.5 * (mL + mR) - 0.5 * a * (hsR - hsL)
-        Fq = 0.5 * ((mL * uL + 0.5 * G * hsL * hsL) + (mR * uR + 0.5 * G * hsR * hsR)) \
-            - 0.5 * a * (mR - mL)
-        # momentum flux + well-balanced interface correction, as seen from
-        # the left cell (A) and from the right cell (B)
-        A = Fq + 0.5 * G * (h[:-1] ** 2 - hsL**2)
-        B = Fq + 0.5 * G * (h[1:] ** 2 - hsR**2)
+        Fh, Fq, hsL, hsR = swe1d.face_flux(h[:-1], h[1:], bL, bR, bstar, u[:-1], u[1:],
+                                           sqrt=_sqrt_safe)
+        A, B = swe1d.well_balanced(Fq, h[:-1], h[1:], hsL, hsR)
         # flux divergence per cell; reflective walls (zero mass flux,
         # hydrostatic pressure G/2 h^2)
         div_h = jnp.concatenate([Fh[:1], Fh[1:] - Fh[:-1], -Fh[-1:]], 0)
         pL = 0.5 * G * h[:1] ** 2
         pR = 0.5 * G * h[-1:] ** 2
         div_hu = jnp.concatenate([A[:1] - pL, A[1:] - B[:-1], pR - B[-1:]], 0)
-        h_new = jnp.maximum(h - dt_dx * div_h, 0.0)
-        hu_new = jnp.where(h_new > H_DRY, hu - dt_dx * div_hu, 0.0)
-        return h_new, hu_new
+        return swe1d.update(h, hu, div_h, div_hu, dt_dx)
 
     return step
+
+
+def _buoy_rows(n_cells: int) -> tuple:
+    """The cells of the 1-D buoys on a transect of `n_cells` cells."""
+    dx = L_DOMAIN / n_cells
+    return tuple(int(bk * 1e3 / dx) for bk in BUOYS_KM)
+
+
+def _transect_source(thetas, n_cells: int, smoothed: bool):
+    """[N, 2] sources (x0 [km], A [m]) -> the initial depth [n_cells, N]:
+    still water plus A exp(-((x - x0) / 25 km)^2) where it is wet."""
+    dx = L_DOMAIN / n_cells
+    x = jnp.asarray((np.arange(n_cells) + 0.5) * dx, jnp.float32)[:, None]
+    h0s = jnp.maximum(-jnp.asarray(_bathymetry_cached(n_cells, smoothed))[:, None], 0.0)
+    x0 = thetas[None, :, 0] * 1e3  # [1, N]
+    amp = thetas[None, :, 1]
+    eta0 = amp * jnp.exp(-(((x - x0) / 25e3) ** 2))  # [C, N]
+    return jnp.maximum(h0s + eta0 * (h0s > H_DRY), 0.0)
+
+
+def _transect_rows(mx, arr, dt: float):
+    """(max elevation, arrival step), each [2, N] -> [N, 4] rows
+    [a1, h1, a2, h2], arrival in minutes (T_END where the wave never
+    came)."""
+    arrival = jnp.where(arr >= 0, arr * (dt / 60.0), T_END / 60.0)
+    return jnp.stack([arrival, mx], axis=2).transpose(1, 0, 2).reshape(mx.shape[1], 4)
 
 
 @partial(jax.jit, static_argnames=("n_cells", "smoothed", "swe_impl"))
@@ -156,7 +169,46 @@ def _solve_batch(
     thetas: jax.Array, n_cells: int, smoothed: bool, swe_impl: str = "scan"
 ) -> jax.Array:
     """[N, 2] -> [N, 4]: ONE jitted program solving all N sources in lockstep,
-    a `lax.scan` of `_transect_step` over the time steps.
+    along the path `swe1d.transect_path` picks for the platform: on a TPU
+    the whole time loop in one Pallas kernel (`_solve_kernel`), elsewhere
+    the `lax.scan` of `_transect_step` (`_solve_scan`). Its derivatives are
+    the scan's on every platform.
+
+    `swe_impl` is ignored: it is kept, static, only so that wrappers in
+    `tests/bench/faults.py` that pass it through still call this program."""
+    return _transect_observables(thetas, n_cells, smoothed, swe1d.transect_path())
+
+
+def _transect_observables(thetas, n_cells: int, smoothed: bool, path: str):
+    """`_solve_batch` along `path` ("pallas" or "xla")."""
+    if path == "pallas":
+        return _solve_kernel(thetas, n_cells, smoothed)
+    return _solve_scan(thetas, n_cells, smoothed)
+
+
+@partial(jax.custom_jvp, nondiff_argnums=(1, 2))
+def _solve_kernel(thetas: jax.Array, n_cells: int, smoothed: bool) -> jax.Array:
+    """[N, 2] -> [N, 4] through the time-loop kernel (`swe1d.transect_solve`),
+    chains on sublanes and cells on lanes; under a mesh each device solves
+    the chains of its shard. Derivatives are the scan's (`jax.jvp` of
+    `_solve_scan`), with its clamped adjoint and remat: only evaluate-only
+    programs run the kernel."""
+    grid = Grid(n_cells, smoothed=smoothed)
+    h = _transect_source(thetas, n_cells, smoothed)
+    mx, arr = swe1d.transect_solve(
+        h.T, _bathymetry_cached(n_cells, smoothed), steps=grid.steps,
+        lam=grid.dt / (L_DOMAIN / n_cells), buoys=_buoy_rows(n_cells), thresh=ARRIVAL_THRESH)
+    return _transect_rows(mx, arr, grid.dt)
+
+
+@_solve_kernel.defjvp
+def _solve_kernel_jvp(n_cells, smoothed, primals, tangents):
+    return jax.jvp(partial(_solve_scan, n_cells=n_cells, smoothed=smoothed), primals, tangents)
+
+
+def _solve_scan(thetas: jax.Array, n_cells: int, smoothed: bool) -> jax.Array:
+    """[N, 2] -> [N, 4] as a `lax.scan` of `_transect_step` over the time
+    steps:
 
       * state is laid out [n_cells, N] (batch LAST): every stencil slice
         (`h[:-1]`, `h[1:]`) and boundary concatenate is then a contiguous
@@ -164,32 +216,22 @@ def _solve_batch(
       * the arrival-time / max-height observable reduction runs INSIDE the
         scan carry, so only [N, 4] ever leaves the device;
       * buoys are read with static row slices instead of a gather, and the
-        face bathymetry is computed once, outside the loop.
-
-    `swe_impl` is ignored: it is kept, static, only so that wrappers in
-    `tests/bench/faults.py` that pass it through still call this program."""
-    dx = L_DOMAIN / n_cells
-    x = jnp.asarray((np.arange(n_cells) + 0.5) * dx, jnp.float32)[:, None]
+        face bathymetry is computed once, outside the loop."""
+    grid = Grid(n_cells, smoothed=smoothed)
+    buoys = _buoy_rows(n_cells)
     b = jnp.asarray(_bathymetry_cached(n_cells, smoothed))[:, None]  # [C, 1]
     h0s = jnp.maximum(-b, 0.0)
-    dt = CFL * dx / C_MAX
-    advance = _transect_step(b, dt / dx)
-
-    n_steps = int(T_END / dt)
-    buoy_rows = tuple(int(bk * 1e3 / dx) for bk in BUOYS_KM)
-    h0_buoy = jnp.stack([h0s[r, 0] for r in buoy_rows])  # [2]
+    advance = _transect_step(b, grid.dt / (L_DOMAIN / n_cells))
+    h0_buoy = jnp.stack([h0s[r, 0] for r in buoys])  # [2]
 
     N = thetas.shape[0]
-    x0 = thetas[None, :, 0] * 1e3  # [1, N]
-    amp = thetas[None, :, 1]
-    eta0 = amp * jnp.exp(-(((x - x0) / 25e3) ** 2))  # [C, N]
-    h = jnp.maximum(h0s + eta0 * (h0s > H_DRY), 0.0)
+    h = _transect_source(thetas, n_cells, smoothed)
     hu = jnp.zeros_like(h)
 
     def step(carry, i):
         h, hu, mx, arr = carry
         h_new, hu_new = advance(h, hu)
-        eta_b = jnp.stack([h_new[r] for r in buoy_rows], 0) - h0_buoy[:, None]  # [2, N]
+        eta_b = jnp.stack([h_new[r] for r in buoys], 0) - h0_buoy[:, None]  # [2, N]
         mx = jnp.maximum(mx, eta_b)
         arr = jnp.where((jnp.abs(eta_b) > ARRIVAL_THRESH) & (arr < 0), i, arr)
         return (h_new, hu_new, mx, arr), None
@@ -203,11 +245,9 @@ def _solve_batch(
     # and ~5x smaller footprint at [512, 8]). Forward-only runs are
     # untouched (checkpoint is an AD-time construct).
     (_, _, mx, arr), _ = jax.lax.scan(
-        jax.checkpoint(step), init, jnp.arange(n_steps, dtype=jnp.float32)
+        jax.checkpoint(step), init, jnp.arange(grid.steps, dtype=jnp.float32)
     )
-    arrival = jnp.where(arr >= 0, arr * (dt / 60.0), T_END / 60.0)
-    # [2, N] obs pairs -> [N, 4] rows [a1, h1, a2, h2]
-    return jnp.stack([arrival, mx], axis=2).transpose(1, 0, 2).reshape(N, 4)
+    return _transect_rows(mx, arr, grid.dt)
 
 
 @partial(jax.jit, static_argnames=("n_cells", "smoothed"))
@@ -562,7 +602,10 @@ class TsunamiModel(Model):
         level, grid = self._grid(op, config)
         operands = [np.atleast_2d(np.asarray(x, np.float32)) for x in operands]
         chunk_max = _CHUNK_MAX if op == "evaluate" else self.GRAD_CHUNK_MAX
-        kernel = swe2d.sea_path(grid.ny, grid.nx) if grid.ny else "xla"
+        if grid.ny:
+            kernel = swe2d.sea_path(grid.ny, grid.nx)
+        else:  # derivative programs are the scan's on every platform
+            kernel = swe1d.transect_path() if op == "evaluate" else "xla"
 
         def solve(lo, hi, bucket):
             out = program(grid, *(jnp.asarray(pad_to_bucket(x[lo:hi], bucket)[0])

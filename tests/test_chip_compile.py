@@ -11,13 +11,15 @@ described inside a module fixture, never while a module is imported, so every
 test worker collects the same tests and only the worker given this file loads
 the TPU compiler.
 """
+import re
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.apps import tsunami as ts
 from repro.apps.tsunami import (
@@ -27,8 +29,10 @@ from repro.apps.tsunami import (
     _solve_batch_2d,
     _vjp_batch,
 )
+from repro.distributed.sharding import ShardingCtx, chain_carry_shardings
 from repro.kernels.swe2d import ops as swe2d_ops
 from repro.kernels.swe2d import sea_kernel
+from repro.uq import fused
 from repro.uq.gp import _kernel
 
 HBM_BYTES = 16e9  # device memory of one v5e chip
@@ -123,6 +127,52 @@ def test_2d_sea_kernel_compiles_for_v5e_at_a_16_lane_chunk(one_chip, level):
     bath = _shape(one_chip, grid.ny + 8, w)
     compiled = jax.jit(run).lower(_shape(one_chip, 16, grid.ny, w), bath, bath, bath).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture()
+def tpu_path(monkeypatch):
+    """The described chip is not attached, so `jax.default_backend()` says
+    "cpu": answer "tpu" for the program's path choice, as the chip would,
+    and trace the 1-D program afresh on both sides of the answer."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _solve_batch.clear_cache()
+    yield
+    _solve_batch.clear_cache()
+
+
+@pytest.mark.parametrize("level,lanes", [(1, 128), (0, 16)])
+def test_1d_kernel_compiles_for_v5e(one_chip, tpu_path, level, lanes):
+    """The 1-D time-loop kernel at the widest wave of the fine level and at
+    the chunk width a 128-point wave takes on the chip's host."""
+    grid = TsunamiModel().grids[level]
+    compiled = _solve_batch.lower(_shape(one_chip, lanes, 2), grid.nx, grid.smoothed).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+def test_fused_block_on_four_chips_solves_each_chips_chains(topo, tpu_path):
+    """The four-chip cell's fused block: 512 chains sharded 128 per chip,
+    each chip's kernel on its own [128, 512] chains, and no collective
+    gathering the chains in front of it."""
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(4, 1), ("data", "model"))
+    ctx, K = ShardingCtx(mesh), 512
+    target = fused.gaussian_likelihood_target(
+        partial(_solve_batch, n_cells=512, smoothed=True), np.ones(4), np.ones(4),
+        ((30.0, 150.0), (0.5, 4.0)))
+    step = fused._rwm_step(target, np.eye(2), jnp.ones(K, bool))
+    carry = {"key": jax.random.key(0), "xs": jnp.zeros((K, 2)), "lps": jnp.zeros(K),
+             "acc": jnp.zeros(K)}
+    csh = chain_carry_shardings(ctx, carry, K)
+    ysh = ctx.sharding(None, "batch")
+    block = jax.jit(lambda c: jax.lax.scan(step, c, None, length=25),
+                    in_shardings=(csh,), out_shardings=(csh, (ysh, ysh)))
+    shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=csh[k])
+              for k, v in carry.items()}
+    with mesh:
+        text = block.lower(shapes).compile().as_text()
+    kernel = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernel) == 1 and "f32[128,512]" in kernel[0]
+    assert not re.search(r"all-gather|all-to-all|all-reduce|collective-permute", text)
 
 
 def test_gp_kernel_compiles_for_v5e(one_chip):
